@@ -6,6 +6,9 @@ export PYTHONPATH := src
         bench bench-smoke bench-gate docs-lint docs-lint-fast check report \
         report-smoke report-paper examples-smoke service-smoke
 
+# tests run on the CPU (Pallas in interpret mode): no test takes a chip
+test test-fast test-batched test-chaos test-traces test-hetero: export JAX_PLATFORMS := cpu
+
 test:            ## tier-1 verification (what CI gates on) — the full suite
 	$(PY) -m pytest -x -q
 

@@ -18,9 +18,9 @@ import time
 import numpy as np
 
 from repro.core.fairshare import (autotune_crossover, maxmin_fair_jax,
-                                  maxmin_fair_numpy, phase_worst_accel,
-                                  phase_worst_jax, phase_worst_numpy,
-                                  problem_size)
+                                  maxmin_fair_numpy, phase_worst_jax,
+                                  phase_worst_numpy, problem_size)
+from repro.kernels.phase_max import phase_worst_pallas
 
 #: (nvals, nsegments) CSR shapes observed at :func:`phase_worst_loads`
 #: dispatch inside ``run_lanes`` on a fabric-heavy 72-lane campaign
@@ -103,9 +103,9 @@ def run(fast: bool = True):
         ptr = np.sort(rng.integers(0, nvals, nseg - 1))
         ptr = np.concatenate([[0], ptr, [nvals]]).astype(np.int64)
         t_np = _best_of(phase_worst_numpy, vals, ptr)
-        t_ac = _best_of(phase_worst_accel, vals, ptr)
+        t_ac = _best_of(phase_worst_pallas, vals, ptr)
         exact = bool((phase_worst_numpy(vals, ptr)
-                      == np.asarray(phase_worst_accel(vals, ptr))).all())
+                      == phase_worst_pallas(vals, ptr)).all())
         assert exact, f"phase_worst backends disagree at {tag} shape"
         shape_rows[tag] = {"nvals": nvals, "nseg": nseg,
                            "numpy_us": round(t_np * 1e6, 1),

@@ -106,10 +106,13 @@ def run(fast: bool = True):
             [sys.executable, "-c", _CHILD, path, str(WINDOW_JOBS),
              str(STRIDE_JOBS), str(max_windows)],
             capture_output=True, text=True,
-            env=dict(os.environ, PYTHONPATH=os.pathsep.join(
-                filter(None, [os.path.join(os.path.dirname(__file__),
-                                           os.pardir, "src"),
-                              os.environ.get("PYTHONPATH", "")]))))
+            # the replay is host work: the child never takes the chip
+            # from a parent that may own it (docs/robustness.md)
+            env=dict(os.environ, JAX_PLATFORMS="cpu",
+                     PYTHONPATH=os.pathsep.join(
+                         filter(None, [os.path.join(os.path.dirname(__file__),
+                                                    os.pardir, "src"),
+                                       os.environ.get("PYTHONPATH", "")]))))
         if r.returncode != 0:
             raise RuntimeError(f"windowed replay child failed: "
                                f"{r.stderr[-2000:]}")

@@ -65,7 +65,10 @@ def run_example(path: Path, argv: list[str]) -> None:
     try:
         r = subprocess.run(
             [sys.executable, str(path)] + argv, cwd=ROOT, timeout=600,
-            env={**os.environ, "PYTHONPATH": str(ROOT / "src")},
+            # a smoke, not a chip run: the child never takes the chip
+            # (docs/robustness.md)
+            env={**os.environ, "PYTHONPATH": str(ROOT / "src"),
+                 "JAX_PLATFORMS": "cpu"},
             capture_output=True, text=True)
     except subprocess.TimeoutExpired:
         errors.append(f"{path.name} {' '.join(argv)}: timeout > 600s")

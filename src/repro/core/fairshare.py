@@ -18,17 +18,12 @@ when capacities are 1.0).
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Hashable, List, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:  # JAX is a hard dependency of the repo, soft here for import hygiene
-    import jax
-    import jax.numpy as jnp
-    from functools import partial
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover
-    _HAVE_JAX = False
 
 
 def maxmin_fair_numpy(flow_links: Sequence[Sequence[Hashable]],
@@ -87,75 +82,72 @@ def maxmin_fair_numpy(flow_links: Sequence[Sequence[Hashable]],
     return np.clip(rates, 0.0, flow_cap)
 
 
-if _HAVE_JAX:
+@partial(jax.jit, static_argnames=("max_iters",))
+def _maxmin_kernel(incidence: jnp.ndarray, cap: jnp.ndarray,
+                   flow_cap: jnp.ndarray,
+                   max_iters: int = 0) -> jnp.ndarray:
+    """incidence: (links, flows) 0/1; cap: (links,); flow_cap: scalar
+    per-flow ceiling (the NIC tier).  Returns (flows,)."""
+    nlinks, nflows = incidence.shape
+    iters = max_iters or nlinks + 1
 
-    @partial(jax.jit, static_argnames=("max_iters",))
-    def _maxmin_kernel(incidence: jnp.ndarray, cap: jnp.ndarray,
-                       flow_cap: jnp.ndarray,
-                       max_iters: int = 0) -> jnp.ndarray:
-        """incidence: (links, flows) 0/1; cap: (links,); flow_cap: scalar
-        per-flow ceiling (the NIC tier).  Returns (flows,)."""
-        nlinks, nflows = incidence.shape
-        iters = max_iters or nlinks + 1
+    def body(state):
+        rates, frozen, remaining, it = state
+        act = incidence * (1.0 - frozen)[None, :]
+        nact = act.sum(axis=1)
+        share = jnp.where(nact > 0, remaining / jnp.maximum(nact, 1), jnp.inf)
+        share = jnp.minimum(share, flow_cap)
+        b = jnp.argmin(share)
+        s = share[b]
+        hit = act[b] > 0          # flows on the bottleneck link
+        any_hit = hit.any()
+        new_rates = jnp.where(hit, s, rates)
+        new_frozen = jnp.where(hit, 1.0, frozen)
+        # subtract frozen bandwidth from every link these flows touch
+        used = (incidence * hit[None, :]).sum(axis=1) * s
+        new_remaining = remaining - used
+        done = jnp.logical_not(any_hit)
+        rates = jnp.where(done, rates, new_rates)
+        frozen = jnp.where(done, frozen, new_frozen)
+        remaining = jnp.where(done, remaining, new_remaining)
+        return rates, frozen, remaining, it + 1
 
-        def body(state):
-            rates, frozen, remaining, it = state
-            act = incidence * (1.0 - frozen)[None, :]
-            nact = act.sum(axis=1)
-            share = jnp.where(nact > 0, remaining / jnp.maximum(nact, 1), jnp.inf)
-            share = jnp.minimum(share, flow_cap)
-            b = jnp.argmin(share)
-            s = share[b]
-            hit = act[b] > 0          # flows on the bottleneck link
-            any_hit = hit.any()
-            new_rates = jnp.where(hit, s, rates)
-            new_frozen = jnp.where(hit, 1.0, frozen)
-            # subtract frozen bandwidth from every link these flows touch
-            used = (incidence * hit[None, :]).sum(axis=1) * s
-            new_remaining = remaining - used
-            done = jnp.logical_not(any_hit)
-            rates = jnp.where(done, rates, new_rates)
-            frozen = jnp.where(done, frozen, new_frozen)
-            remaining = jnp.where(done, remaining, new_remaining)
-            return rates, frozen, remaining, it + 1
+    def cond(state):
+        rates, frozen, remaining, it = state
+        act = incidence * (1.0 - frozen)[None, :]
+        return jnp.logical_and(act.sum() > 0, it < iters)
 
-        def cond(state):
-            rates, frozen, remaining, it = state
-            act = incidence * (1.0 - frozen)[None, :]
-            return jnp.logical_and(act.sum() > 0, it < iters)
+    rates0 = jnp.full(nflows, flow_cap, dtype=jnp.float32)
+    frozen0 = (incidence.sum(axis=0) == 0).astype(jnp.float32)
+    state = jax.lax.while_loop(
+        cond, body, (rates0, frozen0, cap.astype(jnp.float32), 0))
+    return jnp.clip(state[0], 0.0, flow_cap)
 
-        rates0 = jnp.full(nflows, flow_cap, dtype=jnp.float32)
-        frozen0 = (incidence.sum(axis=0) == 0).astype(jnp.float32)
-        state = jax.lax.while_loop(
-            cond, body, (rates0, frozen0, cap.astype(jnp.float32), 0))
-        return jnp.clip(state[0], 0.0, flow_cap)
 
-    def maxmin_fair_jax(flow_links: Sequence[Sequence[Hashable]],
-                        capacity: Dict[Hashable, float] | float = 1.0,
-                        flow_cap: float = 1.0) -> np.ndarray:
-        """Dense-incidence wrapper around the jitted water-filling kernel.
-        ``flow_cap`` as in :func:`maxmin_fair_numpy`."""
-        nflows = len(flow_links)
-        link_ids: Dict[Hashable, int] = {}
-        for ls in flow_links:
-            for l in ls:
-                link_ids.setdefault(l, len(link_ids))
-        if not link_ids:
-            return np.full(nflows, float(flow_cap))
-        inc = np.zeros((len(link_ids), nflows), dtype=np.float32)
-        for i, ls in enumerate(flow_links):
-            for l in ls:
-                inc[link_ids[l], i] = 1.0
-        if isinstance(capacity, (int, float)):
-            cap = np.full(len(link_ids), float(capacity), dtype=np.float32)
-        else:
-            cap = np.array([capacity.get(l, 1.0) for l in link_ids],
-                           dtype=np.float32)
-        return np.asarray(_maxmin_kernel(
-            jnp.asarray(inc), jnp.asarray(cap),
-            jnp.float32(flow_cap)))
-else:  # pragma: no cover
-    maxmin_fair_jax = maxmin_fair_numpy
+def maxmin_fair_jax(flow_links: Sequence[Sequence[Hashable]],
+                    capacity: Dict[Hashable, float] | float = 1.0,
+                    flow_cap: float = 1.0) -> np.ndarray:
+    """Dense-incidence wrapper around the jitted water-filling kernel.
+    ``flow_cap`` as in :func:`maxmin_fair_numpy`."""
+    nflows = len(flow_links)
+    link_ids: Dict[Hashable, int] = {}
+    for ls in flow_links:
+        for l in ls:
+            link_ids.setdefault(l, len(link_ids))
+    if not link_ids:
+        return np.full(nflows, float(flow_cap))
+    inc = np.zeros((len(link_ids), nflows), dtype=np.float32)
+    for i, ls in enumerate(flow_links):
+        for l in ls:
+            inc[link_ids[l], i] = 1.0
+    if isinstance(capacity, (int, float)):
+        cap = np.full(len(link_ids), float(capacity), dtype=np.float32)
+    else:
+        cap = np.array([capacity.get(l, 1.0) for l in link_ids],
+                       dtype=np.float32)
+    return np.asarray(_maxmin_kernel(
+        jnp.asarray(inc), jnp.asarray(cap),
+        jnp.float32(flow_cap)))
 
 
 def maxmin_fair(flow_links, capacity=1.0, backend: str = "numpy",
@@ -206,8 +198,6 @@ def autotune_crossover(probe_flows: Sequence[int] = (64, 256, 1024, 4096),
     return the smallest dense size where the JAX kernel wins (``inf`` when
     it never does — the common case on host-only builds).  The result is
     cached module-wide; ``REPRO_MAXMIN_CROSSOVER`` overrides it."""
-    if not _HAVE_JAX:
-        return float("inf")
     rng = np.random.default_rng(seed)
     crossover = float("inf")
     for nflows in probe_flows:
@@ -269,45 +259,42 @@ def phase_worst_numpy(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
     return out
 
 
-if _HAVE_JAX:
-
-    @partial(jax.jit, static_argnames=("num_segments",))
-    def _segment_max_kernel(vals: jnp.ndarray, seg: jnp.ndarray,
-                            num_segments: int) -> jnp.ndarray:
-        out = jax.ops.segment_max(vals, seg, num_segments=num_segments)
-        return jnp.maximum(out, 0)         # empty segments -> 0, not int-min
-
-    def phase_worst_jax(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
-        """JAX twin of :func:`phase_worst_numpy` (identical integer output).
-
-        Pads values and segment count to powers of two so the jitted kernel
-        is reused across the engine's (ragged) event-time batch shapes."""
-        nseg = len(ptr) - 1
-        if not len(vals):
-            return np.zeros(nseg, dtype=np.int64)
-        seg = np.repeat(np.arange(nseg, dtype=np.int32), np.diff(ptr))
-        n = 1 << int(np.ceil(np.log2(max(len(vals), 1))))
-        nseg_pad = 1 << int(np.ceil(np.log2(max(nseg, 1))))
-        vp = np.zeros(n, dtype=np.int32)
-        vp[:len(vals)] = vals
-        sp = np.full(n, nseg_pad - 1, dtype=np.int32)
-        sp[:len(vals)] = seg
-        out = np.asarray(_segment_max_kernel(jnp.asarray(vp),
-                                             jnp.asarray(sp), nseg_pad))
-        res = out[:nseg].astype(np.int64)
-        if nseg == nseg_pad and len(vals) < n:
-            # padding shared the last real segment: recompute it exactly
-            res[-1] = vals[ptr[-2]:].max() if ptr[-1] > ptr[-2] else 0
-        return res
-else:  # pragma: no cover
-    phase_worst_jax = phase_worst_numpy
+@partial(jax.jit, static_argnames=("num_segments",))
+def _segment_max_kernel(vals: jnp.ndarray, seg: jnp.ndarray,
+                        num_segments: int) -> jnp.ndarray:
+    out = jax.ops.segment_max(vals, seg, num_segments=num_segments)
+    return jnp.maximum(out, 0)         # empty segments -> 0, not int-min
 
 
-#: numpy→JAX dispatch size for :func:`phase_worst_loads`.  Resolved from
-#: ``REPRO_PHASE_WORST_CROSSOVER`` once; default ``inf`` (numpy) — the
-#: right call on host-only builds, where the segment-max kernel never wins
-#: (``benchmarks/bench_fairshare.py`` measures both and reports the value
-#: to export on accelerated hosts).  Deliberately *not* autotuned inline:
+def phase_worst_jax(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
+    """JAX twin of :func:`phase_worst_numpy` (identical integer output).
+
+    Pads values and segment count to powers of two so the jitted kernel
+    is reused across the engine's (ragged) event-time batch shapes."""
+    nseg = len(ptr) - 1
+    if not len(vals):
+        return np.zeros(nseg, dtype=np.int64)
+    seg = np.repeat(np.arange(nseg, dtype=np.int32), np.diff(ptr))
+    n = 1 << int(np.ceil(np.log2(max(len(vals), 1))))
+    nseg_pad = 1 << int(np.ceil(np.log2(max(nseg, 1))))
+    vp = np.zeros(n, dtype=np.int32)
+    vp[:len(vals)] = vals
+    sp = np.full(n, nseg_pad - 1, dtype=np.int32)
+    sp[:len(vals)] = seg
+    out = np.asarray(_segment_max_kernel(jnp.asarray(vp),
+                                         jnp.asarray(sp), nseg_pad))
+    res = out[:nseg].astype(np.int64)
+    if nseg == nseg_pad and len(vals) < n:
+        # padding shared the last real segment: recompute it exactly
+        res[-1] = vals[ptr[-2]:].max() if ptr[-1] > ptr[-2] else 0
+    return res
+
+
+#: numpy→Pallas dispatch size for :func:`phase_worst_loads`.  Resolved from
+#: ``REPRO_PHASE_WORST_CROSSOVER`` once; default ``inf`` (numpy) — numpy
+#: wins at every recorded dispatch shape, on the host and on a TPU v5e
+#: chip alike (``chip_smoke.py`` and ``benchmarks/bench_fairshare.py``
+#: time both; PERF.md).  Deliberately *not* autotuned inline:
 #: a JIT-compiling benchmark must never fire mid-simulation, and the
 #: water-filling crossover above is tuned on a different kernel.
 _PW_CROSSOVER_ENV = "REPRO_PHASE_WORST_CROSSOVER"
@@ -322,30 +309,13 @@ def phase_worst_crossover() -> float:
     return _pw_crossover["value"]
 
 
-_pallas_ok: Dict[str, bool] = {}
+def pin_host_dispatch() -> None:
+    """Pin this process's ``"auto"`` dispatch to numpy at every size.
 
-
-def _phase_worst_pallas_ok() -> bool:
-    """Lazy one-shot probe of the Pallas segment-max kernel
-    (``repro.kernels.phase_max``) — import deferred so the numpy-only hot
-    path never pays for a jax import it does not use."""
-    if "value" not in _pallas_ok:
-        try:
-            from repro.kernels.phase_max import phase_max_available
-            _pallas_ok["value"] = phase_max_available()
-        except Exception:
-            _pallas_ok["value"] = False
-    return _pallas_ok["value"]
-
-
-def phase_worst_accel(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
-    """Accelerator path of :func:`phase_worst_loads`: the Pallas kernel
-    when it lowers here, the jitted ``jax.ops.segment_max`` twin otherwise.
-    Integer-exact either way."""
-    if _phase_worst_pallas_ok():
-        from repro.kernels.phase_max import phase_worst_pallas
-        return phase_worst_pallas(vals, ptr)
-    return phase_worst_jax(vals, ptr)
+    The initializer of campaign pool workers (``repro.core.runtime``): a
+    chip belongs to one process, so a worker never reaches for it, whatever
+    ``REPRO_PHASE_WORST_CROSSOVER`` says (docs/robustness.md)."""
+    _pw_crossover["value"] = float("inf")
 
 
 def phase_worst_loads(vals: np.ndarray, ptr: np.ndarray,
@@ -354,15 +324,16 @@ def phase_worst_loads(vals: np.ndarray, ptr: np.ndarray,
     dispatch — the contended-subgraph solve of the v2/batched engines' rate
     resolution.  Integer in/out, so the dispatch can never change a
     schedule.  ``backend``: ``"numpy"`` / ``"jax"`` / ``"pallas"`` force a
-    path (``"pallas"`` falls back to JAX segment-max when the kernel is
-    unavailable); ``"auto"`` uses numpy below the crossover and the
-    accelerator path above it."""
+    path; ``"auto"`` uses numpy below the crossover and the Pallas kernel
+    above it.  A kernel that fails to lower or compile raises: no backend
+    stands in for another."""
     if backend == "numpy":
         return phase_worst_numpy(vals, ptr)
     if backend == "jax":
         return phase_worst_jax(vals, ptr)
-    if backend == "pallas":
-        return phase_worst_accel(vals, ptr)
-    if len(vals) < phase_worst_crossover():
+    if backend != "pallas" and len(vals) < phase_worst_crossover():
         return phase_worst_numpy(vals, ptr)
-    return phase_worst_accel(vals, ptr)
+    # deferred: repro.kernels also carries the LM kernels, which the
+    # numpy-only hot path never needs
+    from repro.kernels.phase_max import phase_worst_pallas
+    return phase_worst_pallas(vals, ptr)

@@ -510,8 +510,7 @@ class CellRunner:
     # -- pool mode ----------------------------------------------------------
     def run_pool(self, indices: Sequence[int],
                  ) -> Tuple[Dict[int, CellOutcome], Dict[int, FailedCell]]:
-        from concurrent.futures import (FIRST_COMPLETED, ProcessPoolExecutor,
-                                        wait)
+        from concurrent.futures import FIRST_COMPLETED, wait
         from concurrent.futures.process import BrokenProcessPool
 
         cfg = self.config
@@ -523,7 +522,7 @@ class CellRunner:
         queue = deque(indices)
         suspects: Set[int] = set()     # in flight at an unattributed crash
         inflight: Dict[object, Tuple[int, Optional[float]]] = {}
-        pool = ProcessPoolExecutor(max_workers=workers)
+        pool = host_pool(workers)
         ok = False
 
         def submit(i: int) -> None:
@@ -536,7 +535,7 @@ class CellRunner:
         def rebuild() -> None:
             nonlocal pool
             _shutdown_pool(pool, kill=True)
-            pool = ProcessPoolExecutor(max_workers=workers)
+            pool = host_pool(workers)
 
         def retry_or_give_up(i: int, kind: str, error: str,
                              cause: Optional[BaseException] = None) -> None:
@@ -640,6 +639,20 @@ class CellRunner:
             # (the journal already holds every completed cell)
             _shutdown_pool(pool, kill=not ok)
         return results, failed
+
+
+def host_pool(workers: int):
+    """A worker pool whose processes run cells on the host path only.
+
+    A chip belongs to one process: the one that owns it (the campaign's
+    parent).  Each worker pins its rate-resolution dispatch to numpy before
+    it takes a cell, so it never initialises a JAX backend of its own, nor
+    touches one it inherited (docs/robustness.md)."""
+    from concurrent.futures import ProcessPoolExecutor
+
+    from .fairshare import pin_host_dispatch
+    return ProcessPoolExecutor(max_workers=workers,
+                               initializer=pin_host_dispatch)
 
 
 def _shutdown_pool(pool, kill: bool) -> None:

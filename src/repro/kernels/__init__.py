@@ -7,4 +7,4 @@
   ref             — pure-jnp oracles
 """
 from .ops import attention, flash_attention, rwkv6_mix
-from .phase_max import phase_max_available, phase_worst_pallas
+from .phase_max import phase_worst_pallas

@@ -9,32 +9,32 @@ the dense, regular shape a TPU kernel wants.
 
 Layout: the ragged CSR is gathered into one ``(nseg_pad, K_pad)`` int32
 tile — row ``i`` holds segment ``i``'s values, padded with ``INT32_MIN`` so
-padding never wins a max.  The kernel runs a 2-D grid over (segment-block,
+padding never wins a max.  Both sides pad to a power of two (at least one
+block), so a whole campaign compiles a handful of shapes, and every
+compiled shape is kept.  The kernel runs a 2-D grid over (segment-block,
 column-block); the output block index ignores the column axis, so the
 sequential grid revisits each output row-block once per column-block and
 accumulates a running maximum (the standard Pallas reduction idiom: init
 under ``pl.when(j == 0)``, then ``out = max(out, block_max)``).
 
-On CPU the kernel runs in interpret mode (numerically identical, slow);
-``phase_max_available()`` probes lowering once so callers can fall back to
-the jitted ``jax.ops.segment_max`` path (``fairshare.phase_worst_jax``)
-where Pallas is unavailable.  All paths are integer-exact, so dispatch can
-never change a schedule.
+Off the TPU the kernel runs in interpret mode (numerically identical,
+slow); the platform alone picks the mode.  A kernel that fails to lower or
+compile raises — nothing substitutes another backend.  All paths are
+integer-exact, so dispatch can never change a schedule.
+
+Every call records the JAX monitoring event :data:`CALL_EVENT` with its
+padded ``shape``, so a listener (``jax.monitoring.register_event_listener``)
+can count kernel rounds and the shapes they compiled.
 """
 
 from __future__ import annotations
 
-from functools import lru_cache, partial
+from functools import cache
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover - jax is a baked-in dependency here
-    _HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 _I32_MIN = -(2 ** 31)
 
@@ -42,6 +42,9 @@ _I32_MIN = -(2 ** 31)
 # tile so non-divisible inputs only pad, never re-layout
 _BLOCK_S = 128
 _BLOCK_K = 128
+
+#: recorded once per kernel dispatch (jax.monitoring)
+CALL_EVENT = "/repro/phase_max/call"
 
 
 def _row_max_kernel(x_ref, o_ref):
@@ -55,10 +58,10 @@ def _row_max_kernel(x_ref, o_ref):
                              x_ref[...].max(axis=1, keepdims=True))
 
 
-@lru_cache(maxsize=64)
+@cache
 def _row_max_call(nseg_pad: int, k_pad: int, interpret: bool):
-    """Compiled pallas_call for one padded shape (shapes recur across event
-    rounds, so the cache is small and hot)."""
+    """Jitted pallas_call for one padded shape.  Power-of-two padding keeps
+    the set of shapes small, so every one is kept."""
     grid = (nseg_pad // _BLOCK_S, k_pad // _BLOCK_K)
     fn = pl.pallas_call(
         _row_max_kernel,
@@ -67,54 +70,40 @@ def _row_max_call(nseg_pad: int, k_pad: int, interpret: bool):
         out_specs=pl.BlockSpec((_BLOCK_S, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nseg_pad, 1), jnp.int32),
         interpret=interpret,
+        name="phase_row_max",
     )
     return jax.jit(fn)
 
 
-def _pad_up(n: int, block: int) -> int:
-    return max(block, -(-n // block) * block)
+def _bucket(n: int, block: int) -> int:
+    """Smallest power of two >= ``n``, and at least ``block``."""
+    return max(block, 1 << (max(n, 1) - 1).bit_length())
 
 
-def phase_worst_pallas(vals: np.ndarray, ptr: np.ndarray,
-                       interpret: bool | None = None) -> np.ndarray:
-    """Pallas twin of ``fairshare.phase_worst_numpy`` (identical integer
-    output, including empty segments -> 0 and negative values)."""
-    nseg = len(ptr) - 1
-    out = np.zeros(nseg, dtype=np.int64)
-    if not len(vals) or nseg == 0:
-        return out
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    vals = np.asarray(vals)
-    ptr = np.asarray(ptr)
+def _dense_tile(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
+    """CSR -> the kernel's padded ``(nseg_pad, k_pad)`` int32 row tile
+    (host-side gather; the reduction is the kernel's job)."""
     width = np.diff(ptr)
-    k_pad = _pad_up(int(width.max()), _BLOCK_K)
-    nseg_pad = _pad_up(nseg, _BLOCK_S)
-    # CSR -> dense row gather (host-side; the reduction is the kernel's job)
+    k_pad = _bucket(int(width.max()), _BLOCK_K)
+    nseg_pad = _bucket(len(width), _BLOCK_S)
     col = np.arange(k_pad)
     valid = col[None, :] < width[:, None]
     idx = np.where(valid, ptr[:-1, None] + col[None, :], 0)
     dense = np.full((nseg_pad, k_pad), _I32_MIN, dtype=np.int32)
-    dense[:nseg] = np.where(valid, vals[idx], _I32_MIN)
-    res = np.asarray(_row_max_call(nseg_pad, k_pad, interpret)(dense))
-    res = res[:nseg, 0].astype(np.int64)
-    return np.where(width > 0, res, 0)
+    dense[:len(width)] = np.where(valid, vals[idx], _I32_MIN)
+    return dense
 
 
-def phase_max_available() -> bool:
-    """One-shot probe: can the kernel lower and agree with numpy here?
-    (interpret mode on CPU counts as available — it is exact, just slow)."""
-    if not _HAVE_PALLAS:
-        return False
-    if "ok" not in _state:
-        try:
-            vals = np.asarray([3, 1, 4, 1, 5], dtype=np.int64)
-            ptr = np.asarray([0, 2, 2, 5])
-            got = phase_worst_pallas(vals, ptr)
-            _state["ok"] = got.tolist() == [3, 0, 5]
-        except Exception:
-            _state["ok"] = False
-    return _state["ok"]
-
-
-_state: dict = {}
+def phase_worst_pallas(vals: np.ndarray, ptr: np.ndarray) -> np.ndarray:
+    """Pallas twin of ``fairshare.phase_worst_numpy`` (identical integer
+    output, including empty segments -> 0 and negative values)."""
+    nseg = len(ptr) - 1
+    if not len(vals) or nseg == 0:
+        return np.zeros(nseg, dtype=np.int64)
+    vals = np.asarray(vals)
+    ptr = np.asarray(ptr)
+    dense = _dense_tile(vals, ptr)
+    call = _row_max_call(*dense.shape, jax.default_backend() != "tpu")
+    jax.monitoring.record_event(CALL_EVENT, shape="%dx%d" % dense.shape)
+    res = np.asarray(call(dense))[:nseg, 0].astype(np.int64)
+    return np.where(np.diff(ptr) > 0, res, 0)

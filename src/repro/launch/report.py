@@ -457,6 +457,8 @@ def main() -> None:
                      f"directory (use sweep campaign --resume for a "
                      f"single-journal campaign)")
         rd.mkdir(parents=True, exist_ok=True)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.check:
         if args.scale != "smoke":
             ap.error("--check compares the committed smoke artifacts; "

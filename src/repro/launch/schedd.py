@@ -207,6 +207,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                   f"choose from {sorted(COMMANDS)}", file=sys.stderr)
             sys.exit(2)
         return
+    if argv[0] in ("serve", "replay"):      # the clients never compile
+        from .compile_cache import enable_compile_cache
+        enable_compile_cache()
     COMMANDS[argv[0]](argv[1:])
 
 

@@ -439,6 +439,8 @@ def main() -> None:
     else:
         cmd = "dryrun"   # legacy default invocation
     if cmd == "campaign":
+        from .compile_cache import enable_compile_cache
+        enable_compile_cache()
         campaign_main(argv)
     else:
         dryrun_main(argv)

@@ -134,9 +134,7 @@ def moe_apply_a2a(params: Params, x: jnp.ndarray, cfg, *,
     """
     b, s, d = x.shape
     e, k = cfg.moe_num_experts, cfg.moe_top_k
-    # jax.lax.axis_size only exists on newer jax; psum(1) is the portable way
-    ep = (jax.lax.axis_size(ep_axis) if hasattr(jax.lax, "axis_size")
-          else int(jax.lax.psum(1, ep_axis)))
+    ep = jax.lax.axis_size(ep_axis)
     e_local = e // ep
     x_flat = x.reshape(-1, d)
     t = x_flat.shape[0]

@@ -30,18 +30,6 @@ from .ssm import (mamba2_apply, mamba2_init, rwkv6_channel_mix,
                   rwkv6_channel_mix_init, rwkv6_init, rwkv6_time_mix)
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: the top-level alias (with its
-    ``check_vma`` kwarg) only exists on newer releases; older ones ship
-    ``jax.experimental.shard_map`` whose equivalent kwarg is ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -197,12 +185,12 @@ def _moe_block(layer: Params, x: jnp.ndarray, cfg, ctx: ModelContext,
                      "w_up": espec, "w_gate": espec, "w_down": dspec,
                      **({"shared": shared_spec} if shared_spec else {})},
                     P(dp, ctx.ep_axis, None))
-        moe_fn = _shard_map(
+        moe_fn = jax.shard_map(
             lambda mp, xx: moe_apply_a2a(mp, xx, cfg, ep_axis=ctx.ep_axis,
                                          tp_axis=ctx.ep_tp_axis,
                                          mean_axes=ctx.mesh.axis_names),
             mesh=ctx.mesh, in_specs=in_specs,
-            out_specs=(P(dp, ctx.ep_axis, None), P()))
+            out_specs=(P(dp, ctx.ep_axis, None), P()), check_vma=False)
         y, aux = moe_fn(layer["moe"], h)
     else:
         y, aux = moe_apply_dense(layer["moe"], h, cfg)

@@ -106,7 +106,7 @@ def test_moe_a2a_matches_dense():
         params = T.init_lm(cfg, jax.random.PRNGKey(0))
         rng = np.random.default_rng(1)
         toks = jnp.asarray(rng.integers(0, 128, (8, 16)), jnp.int32)
-        with jax.sharding.use_mesh(ctx.mesh) if hasattr(jax.sharding, "use_mesh") else ctx.mesh:
+        with jax.set_mesh(ctx.mesh):
             l_d, aux_d = jax.jit(lambda p, t: T.forward(p, cfg, t, ctx=ctx))(params, toks)
         l_s, aux_s = T.forward(params, cfg, toks)
         err = float(jnp.abs(l_d - l_s).max())

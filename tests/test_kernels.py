@@ -138,12 +138,8 @@ if HAVE_HYPOTHESIS:
 # ---------------------------------------------------------------------------
 
 from repro.core.fairshare import phase_worst_loads, phase_worst_numpy
-from repro.kernels.phase_max import phase_max_available, phase_worst_pallas
-
-needs_phase_max = pytest.mark.skipif(
-    not phase_max_available(),
-    reason="Pallas phase-max kernel does not lower here "
-           "(interpret mode on CPU counts as available)")
+from repro.kernels import phase_max
+from repro.kernels.phase_max import phase_worst_pallas
 
 
 def _csr(rng, nseg, max_width, lo=-50, hi=50):
@@ -153,7 +149,6 @@ def _csr(rng, nseg, max_width, lo=-50, hi=50):
     return vals.astype(np.int64), ptr.astype(np.int64)
 
 
-@needs_phase_max
 def test_phase_max_matches_numpy_mixed():
     """Empty, single-entry and wide segments interleaved in one call."""
     vals = np.asarray([3, 1, 4, 7, 7, -2, 9], dtype=np.int64)
@@ -163,7 +158,6 @@ def test_phase_max_matches_numpy_mixed():
     assert got.tolist() == want.tolist() == [3, 0, 4, 7, 0, 9]
 
 
-@needs_phase_max
 def test_phase_max_empty_links():
     # all-empty segments (idle fabric): every output is 0, not INT32_MIN
     ptr = np.zeros(9, dtype=np.int64)
@@ -174,7 +168,6 @@ def test_phase_max_empty_links():
                               np.asarray([0])).tolist() == []
 
 
-@needs_phase_max
 def test_phase_max_single_job_links():
     # width-1 segments: output is the value itself, negatives preserved
     vals = np.asarray([5, -3, 0, 17], dtype=np.int64)
@@ -183,7 +176,6 @@ def test_phase_max_single_job_links():
     assert got.tolist() == [5, -3, 0, 17]
 
 
-@needs_phase_max
 def test_phase_max_ties():
     # duplicate maxima within and across segments
     vals = np.asarray([8, 8, 8, 2, 8, 8], dtype=np.int64)
@@ -191,7 +183,6 @@ def test_phase_max_ties():
     assert phase_worst_pallas(vals, ptr).tolist() == [8, 8]
 
 
-@needs_phase_max
 @pytest.mark.parametrize("nseg,max_width", [
     (1, 1),        # single cell, far below one (128, 128) block
     (127, 5),      # one row short of the segment block
@@ -207,7 +198,6 @@ def test_phase_max_nondivisible_grid_shapes(nseg, max_width):
     assert got.dtype == np.int64
 
 
-@needs_phase_max
 def test_phase_worst_loads_pallas_backend_dispatch():
     """The engine-facing entry point routes backend="pallas" through the
     kernel and stays integer-identical to the numpy path."""
@@ -218,8 +208,37 @@ def test_phase_worst_loads_pallas_backend_dispatch():
         phase_worst_loads(vals, ptr, backend="numpy"))
 
 
+def test_phase_max_power_of_two_buckets(monkeypatch):
+    """Ragged event rounds share power-of-two tiles: one compiled shape
+    serves every (nseg, width) inside a bucket."""
+    assert [phase_max._bucket(n, 128) for n in (1, 128, 129, 256, 257)] \
+        == [128, 128, 256, 256, 512]
+    asked = set()
+    real = phase_max._row_max_call
+    monkeypatch.setattr(phase_max, "_row_max_call",
+                        lambda *key: asked.add(key[:2]) or real(*key))
+    rng = np.random.default_rng(7)
+    for nseg, max_width in ((129, 130), (200, 180), (256, 256)):
+        vals, ptr = _csr(rng, nseg, max_width)
+        ptr[-1] = ptr[-2] + max_width       # pin the widest segment
+        vals = rng.integers(-50, 50, size=int(ptr[-1]))
+        np.testing.assert_array_equal(phase_worst_pallas(vals, ptr),
+                                      phase_worst_numpy(vals, ptr))
+    assert asked == {(256, 256)}
+
+
+def test_phase_worst_loads_pallas_failure_raises(monkeypatch):
+    """A kernel that fails to lower raises through the engine-facing entry
+    point: no other backend's result stands in for the kernel's."""
+    def refuse(*shape):
+        raise RuntimeError("Mosaic lowering failed")
+    monkeypatch.setattr(phase_max, "_row_max_call", refuse)
+    vals, ptr = _csr(np.random.default_rng(1), 8, 4, lo=0, hi=100)
+    with pytest.raises(RuntimeError, match="Mosaic lowering failed"):
+        phase_worst_loads(vals, ptr, backend="pallas")
+
+
 if HAVE_HYPOTHESIS:
-    @needs_phase_max
     @settings(max_examples=15, deadline=None)
     @given(nseg=st.integers(1, 64), max_width=st.integers(0, 24),
            seed=st.integers(0, 2 ** 16))

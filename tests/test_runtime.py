@@ -355,7 +355,31 @@ def test_shutdown_pool_kills_hung_workers():
     _shutdown_pool(pool, kill=True)
     for p in procs:
         p.join(timeout=10.0)
-        assert not p.is_alive()                 # dead, not sleeping out 300s
+        # the pool's manager thread may reap the worker first, and then
+        # is_alive() reports the reaped pid as alive: ask the kernel too
+        assert not (p.is_alive() and os.path.exists(f"/proc/{p.pid}")), \
+            "worker still running"              # dead, not sleeping out 300s
+
+
+def _dispatch_crossover():
+    from repro.core.fairshare import phase_worst_crossover
+    return phase_worst_crossover()
+
+
+def test_pool_workers_pin_host_dispatch(monkeypatch):
+    """Campaign pool workers never reach for the chip: their auto dispatch
+    is numpy at every size, whatever the crossover says in the parent."""
+    from repro.core import fairshare
+    from repro.core.runtime import _shutdown_pool, host_pool
+    monkeypatch.setenv("REPRO_PHASE_WORST_CROSSOVER", "0")
+    monkeypatch.setattr(fairshare, "_pw_crossover", {})
+    assert fairshare.phase_worst_crossover() == 0.0     # the parent's
+    pool = host_pool(1)
+    try:
+        got = pool.submit(_dispatch_crossover).result(timeout=60)
+    finally:
+        _shutdown_pool(pool, kill=False)
+    assert got == float("inf")
 
 @pytest.mark.slow
 @pytest.mark.parametrize("store", ["full", "stream"])
