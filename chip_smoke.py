@@ -22,7 +22,10 @@ One process, no children.  Phases, each of which raises on failure:
    the recorded dispatch shapes (``benchmarks/bench_fairshare.py``), and
    the water-filling kernel must match numpy within 1e-6 above
    ``AUTOTUNE_FLOOR``; their median times are printed as chip numbers for
-   ROADMAP A2, not as a benchmark.
+   ROADMAP A2, not as a benchmark.  Every time is a host clock through
+   ``block_until_ready``, so a call on a device-resident tile gives the
+   dispatch-and-sync floor, not the kernel's device time, which only a
+   trace gives (``benchmarks/probe_dispatch.py``).
 
 ``--rehearse`` cuts the campaign to 200 jobs and the timing repeats to 3;
 off the TPU the kernels run in Pallas interpret mode.  A rehearsal never
@@ -142,14 +145,14 @@ def _median_us(fn, *args, repeats: int) -> float:
     return statistics.median(times) * 1e6
 
 
-def phase_kernels(repeats: int, label: str, seed: int) -> None:
+def phase_kernels(repeats: int, label: str) -> None:
     import jax
     from benchmarks.bench_fairshare import BATCHED_DISPATCH_SHAPES
     from repro.core.fairshare import (AUTOTUNE_FLOOR, maxmin_fair_jax,
                                       maxmin_fair_numpy, phase_worst_jax,
                                       phase_worst_numpy, problem_size)
     from repro.kernels import phase_max
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(0)
     interpret = jax.default_backend() != "tpu"
     for tag, nvals, nseg in BATCHED_DISPATCH_SHAPES:
         vals = rng.integers(1, 40, nvals).astype(np.int64)
@@ -166,7 +169,8 @@ def phase_kernels(repeats: int, label: str, seed: int) -> None:
             ("numpy", phase_worst_numpy, (vals, ptr)),
             ("segment_max", phase_worst_jax, (vals, ptr)),
             ("pallas", phase_max.phase_worst_pallas, (vals, ptr)),
-            ("pallas kernel alone", kernel, (tile,)))}
+            ("pallas call on a device-resident tile (dispatch+sync)",
+             kernel, (tile,)))}
         print(f"[kernels] {tag} {nvals} values / {nseg} segments, tile "
               f"{tile.shape[0]}x{tile.shape[1]}: identical outputs; median "
               + ", ".join(f"{k} {v:.1f} us" for k, v in us.items())
@@ -193,8 +197,6 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="reduced size; runs off the TPU (Pallas interpret "
                          "mode) and never prints the ok line")
-    ap.add_argument("--seed", type=int, default=0,
-                    help="seed of the kernel-phase inputs")
     args = ap.parse_args(argv)
 
     import jax
@@ -216,7 +218,7 @@ def main(argv=None) -> int:
     phase_campaign(200 if args.rehearse else 10_000, probe)
     label = (f"{device['kind']} chip numbers for ROADMAP A2, not a benchmark"
              if on_tpu else "CPU rehearsal, not a device number")
-    phase_kernels(3 if args.rehearse else 50, label, args.seed)
+    phase_kernels(3 if args.rehearse else 50, label)
     if args.rehearse:
         print("[done] rehearsal: every phase ran; no ok line",
               file=sys.stderr)
