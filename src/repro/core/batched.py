@@ -46,6 +46,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .fairshare import phase_worst_loads
 from .jobs import GBPS, Job
 from .metrics import MetricsReport, job_metrics
@@ -860,6 +862,7 @@ class _BatchedEngine:
     # -- round loop ----------------------------------------------------------
     def run(self) -> None:
         lanes = self.lanes
+        span = obs.span
         inf = math.inf
         gps = self.spec.gpus_per_server
         live_idx = np.arange(len(lanes))
@@ -934,8 +937,10 @@ class _BatchedEngine:
                 self.ai_a[arows] += 1
                 # the padded extra column makes the off-end gather read inf
                 self.arr_next[arows] = self.j_arr[arows, self.ai_a[arows]]
-            self._schedule_lanes(live_idx)
-            self._recompute()
+            with span("lanes.schedule"):
+                self._schedule_lanes(live_idx)
+            with span("lanes.rate"):
+                self._recompute()
 
 
 # ---------------------------------------------------------------------------
@@ -977,26 +982,29 @@ def run_lanes(spec, lanes_in: Sequence[tuple],
             "heterogeneous specs do not qualify for the batched engine; "
             "run engine='batched' through ClusterSimulator (it delegates "
             "to the bit-identical v2 path) or use engine='v2' directly")
-    ls = LinkSpace(spec)
-    lanes = []
-    for i, (jobs, strat, seed) in enumerate(lanes_in):
-        # the type check matters beyond routing: e.g. vclos routes like an
-        # isolated fast strategy but places via vclos_place, which this
-        # engine does not replicate — letting it through would silently
-        # produce wrong schedules instead of an error
-        if type(strat) not in _FAST_STRATEGY_TYPES:
-            raise ValueError(f"strategy {strat.name!r} does not qualify "
-                             "for the batched engine")
-        routing = strat.make_routing(spec, seed)
-        if not _routing_qualifies(routing):   # pragma: no cover - guarded
-            raise ValueError(f"strategy {strat.name!r} routing does not "
-                             "qualify for the batched engine")
-        pres = _pres_for(jobs, spec.link_gbps)
-        lanes.append(_Lane(i, spec, ls, list(jobs), pres, routing,
-                           strat.isolated))
-    engine = _BatchedEngine(spec, lanes, pw_backend=pw_backend)
-    engine.run()
-    return [_lane_report(ln) for ln in lanes]
+    with obs.span("lanes.prepare"):
+        ls = LinkSpace(spec)
+        lanes = []
+        for i, (jobs, strat, seed) in enumerate(lanes_in):
+            # the type check matters beyond routing: e.g. vclos routes
+            # like an isolated fast strategy but places via vclos_place,
+            # which this engine does not replicate — letting it through
+            # would silently produce wrong schedules instead of an error
+            if type(strat) not in _FAST_STRATEGY_TYPES:
+                raise ValueError(f"strategy {strat.name!r} does not "
+                                 "qualify for the batched engine")
+            routing = strat.make_routing(spec, seed)
+            if not _routing_qualifies(routing):  # pragma: no cover - guarded
+                raise ValueError(f"strategy {strat.name!r} routing does "
+                                 "not qualify for the batched engine")
+            pres = _pres_for(jobs, spec.link_gbps)
+            lanes.append(_Lane(i, spec, ls, list(jobs), pres, routing,
+                               strat.isolated))
+        engine = _BatchedEngine(spec, lanes, pw_backend=pw_backend)
+    with obs.span("lanes.run"):
+        engine.run()
+    with obs.span("lanes.report"):
+        return [_lane_report(ln) for ln in lanes]
 
 
 def try_run_batched(sim, jobs: List[Job],

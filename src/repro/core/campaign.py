@@ -31,6 +31,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .config import SimConfig
 from .jobs import Job
 from .metrics import MetricsReport, cdf
@@ -381,11 +383,23 @@ def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
     completions keep appending to it.  The merged result is
     **bit-identical** to an uninterrupted run (``tests/test_runtime.py``).
     """
-    config = (config or SimConfig()).with_overrides(
-        incremental=incremental, engine=engine, workers=workers,
-        store=store, ilp_time_limit=ilp_time_limit,
-        cell_timeout=cell_timeout, max_retries=max_retries,
-        quarantine=quarantine)
+    with obs.span("campaign.run"):
+        config = (config or SimConfig()).with_overrides(
+            incremental=incremental, engine=engine, workers=workers,
+            store=store, ilp_time_limit=ilp_time_limit,
+            cell_timeout=cell_timeout, max_retries=max_retries,
+            quarantine=quarantine)
+        return _run_campaign(spec, grid, workload, trace, ocs_spec, config,
+                             progress, journal, resume)
+
+
+def _run_campaign(spec: ClusterSpec, grid: CampaignGrid,
+                  workload: Optional[WorkloadSpec],
+                  trace: Optional[Sequence[Job]],
+                  ocs_spec: Optional[ClusterSpec], config: SimConfig,
+                  progress: Optional[Callable[[str], None]],
+                  journal: Optional[str], resume: Optional[str],
+                  ) -> CampaignResult:
     if journal is not None and resume is not None and journal != resume:
         raise ValueError(
             "pass either journal= (start a fresh journal) or resume= "
@@ -493,17 +507,18 @@ def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
                         groups.setdefault(id(cells[i].spec),
                                           (cells[i].spec, []))[1].append(i)
                 for cell_spec, idxs in groups.values():
-                    lanes_in = []
-                    for i in idxs:
-                        cell = cells[i]
-                        lane_jobs = [_copy.copy(j) for j in cell.trace]
-                        for j in lane_jobs:   # same reset as simulate()
-                            j.start_time = None
-                            j.finish_time = None
-                            j.remaining_iters = None
-                        lanes_in.append((lane_jobs,
-                                         cell.config.resolve_strategy(),
-                                         cell.seed))
+                    with obs.span("lanes.prepare"):
+                        lanes_in = []
+                        for i in idxs:
+                            cell = cells[i]
+                            lane_jobs = [_copy.copy(j) for j in cell.trace]
+                            for j in lane_jobs:   # same reset as simulate()
+                                j.start_time = None
+                                j.finish_time = None
+                                j.remaining_iters = None
+                            lanes_in.append((lane_jobs,
+                                             cell.config.resolve_strategy(),
+                                             cell.seed))
                     tg = time.time()
                     reps = run_lanes(cell_spec, lanes_in)
                     dt = (time.time() - tg) / len(idxs)

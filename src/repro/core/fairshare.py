@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 
 def maxmin_fair_numpy(flow_links: Sequence[Sequence[Hashable]],
                       capacity: Dict[Hashable, float] | float = 1.0,
@@ -327,13 +329,14 @@ def phase_worst_loads(vals: np.ndarray, ptr: np.ndarray,
     path; ``"auto"`` uses numpy below the crossover and the Pallas kernel
     above it.  A kernel that fails to lower or compile raises: no backend
     stands in for another."""
-    if backend == "numpy":
-        return phase_worst_numpy(vals, ptr)
-    if backend == "jax":
-        return phase_worst_jax(vals, ptr)
-    if backend != "pallas" and len(vals) < phase_worst_crossover():
-        return phase_worst_numpy(vals, ptr)
-    # deferred: repro.kernels also carries the LM kernels, which the
-    # numpy-only hot path never needs
-    from repro.kernels.phase_max import phase_worst_pallas
-    return phase_worst_pallas(vals, ptr)
+    with obs.span("rate.solve"):
+        if backend == "numpy":
+            return phase_worst_numpy(vals, ptr)
+        if backend == "jax":
+            return phase_worst_jax(vals, ptr)
+        if backend != "pallas" and len(vals) < phase_worst_crossover():
+            return phase_worst_numpy(vals, ptr)
+        # deferred: repro.kernels also carries the LM kernels, which the
+        # numpy-only hot path never needs
+        from repro.kernels.phase_max import phase_worst_pallas
+        return phase_worst_pallas(vals, ptr)

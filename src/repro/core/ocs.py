@@ -27,6 +27,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .placement import (Placement, PlacementFailure, VirtualClos,
                         stage0_server, stage1_leaf, _factorizations,
                         candidate_sizes)
@@ -321,24 +323,26 @@ def _stage2_single_spine(state: FabricState, job_id: int,
 
 def _stage3_findclos(state: FabricState, job_id: int,
                      n: int) -> Optional[Placement]:
-    spec = state.spec
-    for size in candidate_sizes(n, spec):
-        for l, s in _factorizations(size, spec):
-            sol = _choose_leafs_spines_ocs(state, l, s)
-            if sol is None:
-                continue
-            leaf_alloc, spines = sol
-            need: Dict[Tuple[int, int], int] = {}
-            for leaf, vleafs in leaf_alloc.items():
-                for m in spines:
-                    need[(leaf, m)] = need.get((leaf, m), 0) + vleafs
-            planner = RewirePlanner(state)
-            if not planner.ensure(need):
-                continue
-            planner.apply()
-            return _materialize_ocs(state, job_id, n, leaf_alloc, spines, s,
-                                    need, overalloc=size - n)
-    return None
+    with obs.span("ocs.findclos"):
+        spec = state.spec
+        for size in candidate_sizes(n, spec):
+            for l, s in _factorizations(size, spec):
+                obs.count("ocs.candidates")
+                sol = _choose_leafs_spines_ocs(state, l, s)
+                if sol is None:
+                    continue
+                leaf_alloc, spines = sol
+                need: Dict[Tuple[int, int], int] = {}
+                for leaf, vleafs in leaf_alloc.items():
+                    for m in spines:
+                        need[(leaf, m)] = need.get((leaf, m), 0) + vleafs
+                planner = RewirePlanner(state)
+                if not planner.ensure(need):
+                    continue
+                planner.apply()
+                return _materialize_ocs(state, job_id, n, leaf_alloc, spines,
+                                        s, need, overalloc=size - n)
+        return None
 
 
 def _choose_leafs_spines_ocs(state: FabricState, l: int,

@@ -70,6 +70,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro import obs
+
 from .config import ENGINES, SimConfig
 from .events import (FAIL_GPU_OWNER, FAIL_LINK_OWNER, ClusterEvent,
                      frag_index, validate_events)
@@ -366,8 +368,12 @@ class ClusterSimulator:
         # skip the fabric scans — Strategy.place documents this guarantee
         if self.state.num_free_gpus() < job.num_gpus:
             return PlacementFailure("gpu")
-        return self.strategy_obj.place(self, job.job_id, job.num_gpus,
-                                       job=job)
+        with obs.span("place"):
+            res = self.strategy_obj.place(self, job.job_id, job.num_gpus,
+                                          job=job)
+            if isinstance(res, PlacementFailure):
+                obs.tag("fail")
+        return res
 
     # -- placement-context traffic views (see repro.core.strategies) ---------
     def dense_link_load(self) -> np.ndarray:
