@@ -32,7 +32,50 @@ from repro import obs
 from .placement import (Placement, PlacementFailure, VirtualClos,
                         stage0_server, stage1_leaf, _factorizations,
                         candidate_sizes)
-from .topology import ClusterSpec, FabricState
+from .topology import ClusterSpec, FabricState, ocs_ports
+
+
+# ---------------------------------------------------------------------------
+# Port budget of one placement call
+# ---------------------------------------------------------------------------
+
+class PortBudget:
+    """The fabric's port counts, taken once at the top of a placement call
+    and shared by every (l, s) candidate of its search.
+
+    Lists indexed by leaf ``n`` or spine ``m``:
+
+    * ``idle[n]``: fully idle servers of leaf n;
+    * ``cap[n][m]``, ``reserved[n][m]``, ``spare[n][m]``: channels the
+      circuits make, channels jobs hold, and the difference;
+    * ``leaf_free[n]``: rewirable uplink ports of leaf n, as
+      :meth:`FabricState.leaf_free_ports_ocs` counts them;
+    * ``spine_free[m]``: unreserved downlink channels of spine m, as
+      :meth:`FabricState.spine_free_ports` counts them.
+
+    The counts hold only while the fabric is unchanged: a budget never
+    outlives the call that took it, nor any commit, release or rewire.
+    """
+
+    __slots__ = ("idle", "cap", "reserved", "spare", "leaf_free",
+                 "spine_free")
+
+    def __init__(self, state: FabricState):
+        obs.count("ocs.budget")
+        spec = state.spec
+        self.idle = state.idle_server_counts().tolist()
+        self.cap = state.capacity()
+        self.reserved = state.reserved_matrix()
+        held = [0] * spec.num_leafs
+        if state.xconn_owner:
+            ports = ocs_ports(spec)
+            for k, lp in state.xconn_owner:
+                held[ports[k].leaf_of[lp]] += 1
+        self.spare = [[c - r for c, r in zip(crow, rrow)]
+                      for crow, rrow in zip(self.cap, self.reserved)]
+        self.leaf_free = [spec.uplinks_per_leaf - sum(rrow) - h
+                          for rrow, h in zip(self.reserved, held)]
+        self.spine_free = [sum(col) for col in zip(*self.spare)]
 
 
 # ---------------------------------------------------------------------------
@@ -44,24 +87,25 @@ class RewirePlanner:
 
     Works against live OCS state; movable = circuit whose channel has spare
     (unreserved, unpinned) capacity.  All moves are collected and applied
-    atomically by the caller via ``apply``.
+    atomically by the caller via ``apply``.  A ``budget`` of the same,
+    unchanged fabric saves recounting its spare channels.
     """
 
-    def __init__(self, state: FabricState):
+    def __init__(self, state: FabricState,
+                 budget: Optional[PortBudget] = None):
         assert state.ocs is not None, "OCS layer required"
         self.state = state
         self.spec = state.spec
         self.ocs = state.ocs
-        # working copies
+        # working copies (ensure pins channels by decrementing spare)
         self.circuits = [dict(c) for c in self.ocs.circuits]
-        cap = self.ocs.capacity()
-        self.spare = [[cap[n][m] - state.reserved(n, m)
-                       for m in range(self.spec.num_spines)]
-                      for n in range(self.spec.num_leafs)]
+        spare = budget.spare if budget is not None else state.free_capacity()
+        self.spare = [row[:] for row in spare]
         self.moves: List[Tuple[int, int, int]] = []     # (k, leaf_port, spine_port)
         self.unwired: List[Tuple[int, int]] = []        # (k, leaf_port) — for xconn
-        self._lports = [self.ocs.leaf_ports(k) for k in range(self.spec.num_ocs)]
-        self._sports = [self.ocs.spine_ports(k) for k in range(self.spec.num_ocs)]
+        ports = ocs_ports(self.spec)
+        self._lports = [p.leaf_ports for p in ports]
+        self._sports = [p.spine_ports for p in ports]
 
     # -- lookups over the working copy --------------------------------------
     def _endpoints(self, k: int):
@@ -325,10 +369,11 @@ def _stage3_findclos(state: FabricState, job_id: int,
                      n: int) -> Optional[Placement]:
     with obs.span("ocs.findclos"):
         spec = state.spec
+        budget = PortBudget(state)   # nothing changes until a candidate applies
         for size in candidate_sizes(n, spec):
             for l, s in _factorizations(size, spec):
                 obs.count("ocs.candidates")
-                sol = _choose_leafs_spines_ocs(state, l, s)
+                sol = _choose_leafs_spines_ocs(state, l, s, budget)
                 if sol is None:
                     continue
                 leaf_alloc, spines = sol
@@ -336,7 +381,7 @@ def _stage3_findclos(state: FabricState, job_id: int,
                 for leaf, vleafs in leaf_alloc.items():
                     for m in spines:
                         need[(leaf, m)] = need.get((leaf, m), 0) + vleafs
-                planner = RewirePlanner(state)
+                planner = RewirePlanner(state, budget)
                 if not planner.ensure(need):
                     continue
                 planner.apply()
@@ -345,22 +390,23 @@ def _stage3_findclos(state: FabricState, job_id: int,
         return None
 
 
-def _choose_leafs_spines_ocs(state: FabricState, l: int,
-                             s: int) -> Optional[Tuple[Dict[int, int], List[int]]]:
+def _choose_leafs_spines_ocs(state: FabricState, l: int, s: int,
+                             budget: PortBudget
+                             ) -> Optional[Tuple[Dict[int, int], List[int]]]:
     """Aggregated port-count selection (eqs. 7–11 with OCS index summed out).
 
     Multiple virtual leafs per physical leaf are allowed (the L_{n,a}
     linearisation): leaf n can host a_n = idle_servers·T // s virtual leafs.
-    Feasibility is pure port counting; circuit realisation is checked by the
-    RewirePlanner afterwards.
+    Feasibility is pure port counting, on ``budget``, the counts of
+    ``state`` taken for this placement call; circuit realisation is checked
+    by the RewirePlanner afterwards.
     """
     spec = state.spec
     req_servers_per_vleaf = s // spec.gpus_per_server
     # capacity of each leaf in virtual leafs, and free movable uplink ports
     avail: List[Tuple[int, int, int]] = []  # (idle_servers, leaf, max_vleafs)
-    for leaf in range(spec.num_leafs):
-        idle = len(state.idle_servers_of_leaf(leaf))
-        free_up = state.leaf_free_ports_ocs(leaf)
+    for leaf, (idle, free_up) in enumerate(zip(budget.idle,
+                                               budget.leaf_free)):
         max_v = min(idle // req_servers_per_vleaf, free_up // s)
         if max_v > 0:
             avail.append((idle, leaf, max_v))
@@ -379,10 +425,8 @@ def _choose_leafs_spines_ocs(state: FabricState, l: int,
     if left:
         return None
     # spines: need l free downlink channels each; best-fit fewest free ports
-    cap = state.capacity()
-    cands = sorted((state.spine_free_ports(m, cap), m)
-                   for m in range(spec.num_spines)
-                   if state.spine_free_ports(m, cap) >= l)
+    cands = sorted((free, m) for m, free in enumerate(budget.spine_free)
+                   if free >= l)
     if len(cands) < s:
         return None
     return leaf_alloc, [m for _, m in cands[:s]]
@@ -448,9 +492,7 @@ def renormalize(state: FabricState, max_moves: int = 64) -> None:
     if state.ocs is None:
         return
     spec, ocs = state.spec, state.ocs
-    cap = state.capacity()
-    spare = [[cap[n][m] - state.reserved(n, m) for m in range(spec.num_spines)]
-             for n in range(spec.num_leafs)]
+    spare = state.free_capacity()
     moves = 0
     for k in range(spec.num_ocs):
         lports = ocs.leaf_ports(k)

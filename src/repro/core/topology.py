@@ -18,6 +18,7 @@ changing the effective capacity matrix ``C[n][m]`` (paper §7, Table 1).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -239,6 +240,46 @@ def apply_gpu_mix(spec: ClusterSpec,
                                server_scale=tuple(scales))
 
 
+@dataclass(frozen=True)
+class OCSPorts:
+    """Port tables of one OCS: what each local port id stands for.
+
+    ``leaf_ports[lp]`` is the leaf-side port's ``(leaf n, uplink j)`` and
+    ``spine_ports[sp]`` the spine-side port's ``(spine m, downlink i)``;
+    ``leaf_of`` and ``spine_of`` hold the first element of each alone.
+    """
+
+    leaf_ports: Tuple[Tuple[int, int], ...]
+    spine_ports: Tuple[Tuple[int, int], ...]
+    leaf_of: Tuple[int, ...]
+    spine_of: Tuple[int, ...]
+
+
+def ocs_ports(spec: ClusterSpec) -> Tuple[OCSPorts, ...]:
+    """The port tables of every OCS of ``spec``, indexed by OCS.
+
+    They depend on the fabric's shape alone, so they are built once per
+    shape and shared, as tuples no caller can change: circuits and
+    occupancy live in :class:`OCSLayer` and :class:`FabricState`.
+    """
+    return _ocs_ports(spec.num_leafs, spec.num_spines, spec.uplinks_per_leaf,
+                      spec.downlinks_per_spine, spec.num_ocs)
+
+
+@functools.lru_cache(maxsize=16)
+def _ocs_ports(num_leafs: int, num_spines: int, uplinks: int,
+               downlinks: int, num_ocs: int) -> Tuple[OCSPorts, ...]:
+    out = []
+    for k in range(num_ocs):
+        lports = tuple((n, j) for n in range(num_leafs)
+                       for j in range(k, uplinks, num_ocs))
+        sports = tuple((m, i) for m in range(num_spines)
+                       for i in range(k, downlinks, num_ocs))
+        out.append(OCSPorts(lports, sports, tuple(n for n, _ in lports),
+                            tuple(m for m, _ in sports)))
+    return tuple(out)
+
+
 @dataclass
 class OCSLayer:
     """MEMS optical-circuit-switch layer between leafs and spines (§7).
@@ -261,15 +302,11 @@ class OCSLayer:
 
     # Port bookkeeping: leaf-side port local id on OCS k enumerates
     # (leaf, uplink j) pairs with j % K == k, ordered by (leaf, j).
-    def leaf_ports(self, k: int) -> List[Tuple[int, int]]:
-        s = self.spec
-        return [(n, j) for n in range(s.num_leafs)
-                for j in range(k, s.uplinks_per_leaf, s.num_ocs)]
+    def leaf_ports(self, k: int) -> Tuple[Tuple[int, int], ...]:
+        return ocs_ports(self.spec)[k].leaf_ports
 
-    def spine_ports(self, k: int) -> List[Tuple[int, int]]:
-        s = self.spec
-        return [(m, i) for m in range(s.num_spines)
-                for i in range(k, s.downlinks_per_spine, s.num_ocs)]
+    def spine_ports(self, k: int) -> Tuple[Tuple[int, int], ...]:
+        return ocs_ports(self.spec)[k].spine_ports
 
     def _wire_uniform(self) -> None:
         """Default wiring realising the uniform bipartite graph.
@@ -296,13 +333,10 @@ class OCSLayer:
         """Effective link-count matrix C[n][m] induced by current circuits."""
         s = self.spec
         cap = [[0] * s.num_spines for _ in range(s.num_leafs)]
-        for k in range(s.num_ocs):
-            lports = self.leaf_ports(k)
-            sports = self.spine_ports(k)
-            for lp, sp in self.circuits[k].items():
-                n, _ = lports[lp]
-                m, _ = sports[sp]
-                cap[n][m] += 1
+        for ports, circuits in zip(ocs_ports(s), self.circuits):
+            leaf_of, spine_of = ports.leaf_of, ports.spine_of
+            for lp, sp in circuits.items():
+                cap[leaf_of[lp]][spine_of[sp]] += 1
         return cap
 
 
@@ -362,15 +396,21 @@ class FabricState:
     def reserved(self, n: int, m: int) -> int:
         return sum(self.link_owner.get((n, m), {}).values())
 
+    def reserved_matrix(self) -> List[List[int]]:
+        """Reserved channels per (leaf, spine), in one pass over the holders."""
+        s = self.spec
+        out = [[0] * s.num_spines for _ in range(s.num_leafs)]
+        for (n, m), holders in self.link_owner.items():
+            out[n][m] += sum(holders.values())
+        return out
+
     def free_channels(self, n: int, m: int, cap: Optional[List[List[int]]] = None) -> int:
         c = (cap or self.capacity())[n][m]
         return c - self.reserved(n, m)
 
     def free_capacity(self) -> List[List[int]]:
-        cap = self.capacity()
-        s = self.spec
-        return [[cap[n][m] - self.reserved(n, m) for m in range(s.num_spines)]
-                for n in range(s.num_leafs)]
+        return [[c - r for c, r in zip(crow, rrow)]
+                for crow, rrow in zip(self.capacity(), self.reserved_matrix())]
 
     # -- GPU / server occupancy ---------------------------------------------
     def gpu_free(self, gpu: int) -> bool:
@@ -413,11 +453,8 @@ class FabricState:
         the OCS can always wire them somewhere."""
         if self.ocs is None:
             return self.leaf_free_uplinks(n)
-        held = 0
-        for k in range(self.spec.num_ocs):
-            lports = self.ocs.leaf_ports(k)
-            held += sum(1 for (kk, lp) in self.xconn_owner
-                        if kk == k and lports[lp][0] == n)
+        ports = ocs_ports(self.spec)
+        held = sum(1 for k, lp in self.xconn_owner if ports[k].leaf_of[lp] == n)
         reserved = sum(self.reserved(n, m) for m in range(self.spec.num_spines))
         return self.spec.uplinks_per_leaf - reserved - held
 

@@ -33,7 +33,8 @@ Spans in the program (each one's name says where it is):
 * ``ocs.findclos``: the virtual-Clos search of OCS placement.
 
 Counters: ``ocs.candidates`` (leaf x spine factorisations the OCS search
-tried) and ``jax.compiles``.
+tried), ``ocs.budget`` (port budgets the OCS search counted, one per
+search, shared by its candidates) and ``jax.compiles``.
 
 Recording follows one thread: spans opened from several threads at once
 nest wrongly.  Spans of process-pool workers are not recorded.  This

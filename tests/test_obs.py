@@ -166,7 +166,8 @@ def test_ocs_counts_agree_with_outside_wrappers(monkeypatch):
     assert s["place"]["count"] == calls["place"]
     assert s["place"]["tags"]["fail"]["count"] == fails["place"]
     assert 0 < s["ocs.findclos"]["count"] <= calls["place"]
-    assert c["ocs.candidates"] >= s["ocs.findclos"]["count"]
+    assert c["ocs.budget"] == s["ocs.findclos"]["count"]
+    assert c["ocs.candidates"] >= c["ocs.budget"]
     assert "rate.solve" not in s       # ocs-vclos is isolated
 
 

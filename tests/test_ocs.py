@@ -3,10 +3,15 @@
 import numpy as np
 import pytest
 
-from repro.core.ocs import (RewirePlanner, ocs_release, ocs_vclos_place,
-                            renormalize)
+from repro.core import ocs
+from repro.core.ocs import (PortBudget, RewirePlanner, ocs_release,
+                            ocs_vclos_place, renormalize)
 from repro.core.placement import PlacementFailure, commit, vclos_place
-from repro.core.topology import CLUSTER512, CLUSTER512_OCS, FabricState
+from repro.core.simulator import simulate
+from repro.core.strategies import get_strategy
+from repro.core.topology import (CLUSTER512, CLUSTER512_OCS, CLUSTER2048_OCS,
+                                 FabricState, OCSLayer, ocs_ports)
+from repro.core.workloads import WorkloadSpec, generate_trace
 
 
 def fresh():
@@ -116,3 +121,192 @@ def test_ocs_relieves_network_fragmentation():
     if blocked_v is not None:
         assert not isinstance(po, PlacementFailure) or po.reason != "network" \
             or True
+
+
+# ---------------------------------------------------------------------------
+# Port tables and the per-call port budget against plain recounts
+# ---------------------------------------------------------------------------
+
+def plain_ports(spec, k):
+    """OCS k's leaf-side and spine-side port tables, built afresh."""
+    lports = [(n, j) for n in range(spec.num_leafs)
+              for j in range(k, spec.uplinks_per_leaf, spec.num_ocs)]
+    sports = [(m, i) for m in range(spec.num_spines)
+              for i in range(k, spec.downlinks_per_spine, spec.num_ocs)]
+    return lports, sports
+
+
+def plain_counts(st):
+    """Every count a PortBudget holds, recounted from the fabric's state
+    with freshly built port tables."""
+    spec = st.spec
+    L, S = spec.num_leafs, spec.num_spines
+    cap = [[0] * S for _ in range(L)]
+    held = [0] * L
+    for k in range(spec.num_ocs):
+        lports, sports = plain_ports(spec, k)
+        for lp, sp in st.ocs.circuits[k].items():
+            cap[lports[lp][0]][sports[sp][0]] += 1
+        for (kk, lp) in st.xconn_owner:
+            if kk == k:
+                held[lports[lp][0]] += 1
+    reserved = [[st.reserved(n, m) for m in range(S)] for n in range(L)]
+    return {
+        "idle": [len(st.idle_servers_of_leaf(n)) for n in range(L)],
+        "cap": cap,
+        "reserved": reserved,
+        "spare": [[cap[n][m] - reserved[n][m] for m in range(S)]
+                  for n in range(L)],
+        "leaf_free": [spec.uplinks_per_leaf - sum(reserved[n]) - held[n]
+                      for n in range(L)],
+        "spine_free": [sum(cap[n][m] - reserved[n][m] for n in range(L))
+                       for m in range(S)],
+    }
+
+
+@pytest.mark.parametrize("spec", [CLUSTER512_OCS, CLUSTER2048_OCS],
+                         ids=["512", "2048"])
+def test_cached_port_tables_equal_fresh_ones(spec):
+    layer = OCSLayer(spec)
+    tables = ocs_ports(spec)
+    assert tables is ocs_ports(spec)
+    assert len(tables) == spec.num_ocs
+    for k, t in enumerate(tables):
+        lports, sports = plain_ports(spec, k)
+        assert layer.leaf_ports(k) == t.leaf_ports == tuple(lports)
+        assert layer.spine_ports(k) == t.spine_ports == tuple(sports)
+        assert t.leaf_of == tuple(n for n, _ in lports)
+        assert t.spine_of == tuple(m for m, _ in sports)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_port_budget_equals_a_plain_recount_after_every_step(seed):
+    """A seeded run of place / commit / release, 2-leaf cross-connects and
+    failed placements among them: after every step the budget, and the
+    fabric's own per-leaf and per-spine counts, equal a plain recount."""
+    rng = np.random.default_rng(seed)
+    st = fresh()
+    spec = st.spec
+    live = {}
+    kinds = set()
+    failures = 0
+    for jid in range(160):
+        if live and rng.random() < 0.4:
+            victim = sorted(live)[int(rng.integers(len(live)))]
+            ocs_release(st, live.pop(victim))
+        else:
+            n = int(rng.choice([8, 16, 24, 32, 48, 64, 96, 128]))
+            p = ocs_vclos_place(st, jid, n)
+            if isinstance(p, PlacementFailure):
+                failures += 1
+            else:
+                commit(st, p)
+                live[jid] = p
+                kinds.add(p.kind)
+        want = plain_counts(st)
+        b = PortBudget(st)
+        for name, value in want.items():
+            assert getattr(b, name) == value, name
+        assert [st.leaf_free_ports_ocs(n) for n in range(spec.num_leafs)] \
+            == want["leaf_free"]
+        cap = st.capacity()
+        assert cap == want["cap"]
+        assert [st.spine_free_ports(m, cap) for m in range(spec.num_spines)] \
+            == want["spine_free"]
+        assert st.free_capacity() == want["spare"]
+    assert failures and {"ocs-xconn", "ocs-vclos"} <= kinds
+
+
+def test_planner_takes_its_own_copy_of_the_budget():
+    st = fresh()
+    b = PortBudget(st)
+    before = [row[:] for row in b.spare]
+    planner = RewirePlanner(st, b)
+    assert planner.ensure({(0, 5): 3, (2, 7): 2})
+    assert b.spare == before
+
+
+# ---------------------------------------------------------------------------
+# The budget-based search places exactly as the per-candidate count did
+# ---------------------------------------------------------------------------
+
+def plain_choose(state, l, s, budget=None):
+    """Stage-3 leaf and spine selection counting straight from the fabric
+    for every candidate, ignoring ``budget``."""
+    spec = state.spec
+    req_servers_per_vleaf = s // spec.gpus_per_server
+    avail = []
+    for leaf in range(spec.num_leafs):
+        idle = len(state.idle_servers_of_leaf(leaf))
+        free_up = state.leaf_free_ports_ocs(leaf)
+        max_v = min(idle // req_servers_per_vleaf, free_up // s)
+        if max_v > 0:
+            avail.append((idle, leaf, max_v))
+    if sum(a[2] for a in avail) < l:
+        return None
+    avail.sort()
+    leaf_alloc = {}
+    left = l
+    for _, leaf, max_v in avail:
+        take = min(max_v, left)
+        if take:
+            leaf_alloc[leaf] = take
+            left -= take
+        if not left:
+            break
+    if left:
+        return None
+    cap = state.capacity()
+    cands = sorted((state.spine_free_ports(m, cap), m)
+                   for m in range(spec.num_spines)
+                   if state.spine_free_ports(m, cap) >= l)
+    if len(cands) < s:
+        return None
+    return leaf_alloc, [m for _, m in cands[:s]]
+
+
+def _ocs_campaign(monkeypatch, seed):
+    """Every job's committed placement and the report of one seeded
+    ocs-vclos run on CLUSTER512_OCS."""
+    placed = {}
+    strat = type(get_strategy("ocs-vclos"))
+    orig = strat.place
+
+    def place(self, ctx, job_id, num_gpus, job=None):
+        out = orig(self, ctx, job_id, num_gpus, job)
+        if not isinstance(out, PlacementFailure):
+            links = out.vclos.links if out.vclos is not None else {}
+            placed[job_id] = (list(out.gpus), dict(links),
+                              list(out.xconn_ports))
+        return out
+
+    monkeypatch.setattr(strat, "place", place)
+    jobs = generate_trace(WorkloadSpec(num_jobs=300, mean_interarrival=80.0,
+                                       max_gpus=256, seed=seed))
+    report = simulate(CLUSTER512_OCS, jobs, "ocs-vclos")
+    monkeypatch.setattr(strat, "place", orig)
+    return placed, report
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_budget_search_places_exactly_as_the_plain_count(monkeypatch, seed):
+    calls = {"candidates": 0, "found": 0}
+    budgeted = ocs._choose_leafs_spines_ocs
+
+    def checked(state, l, s, budget):
+        got = budgeted(state, l, s, budget)
+        assert got == plain_choose(state, l, s)
+        calls["candidates"] += 1
+        calls["found"] += got is not None
+        return got
+
+    monkeypatch.setattr(ocs, "_choose_leafs_spines_ocs", checked)
+    placed, report = _ocs_campaign(monkeypatch, seed)
+    assert calls["found"] > 0 and calls["candidates"] > calls["found"]
+
+    monkeypatch.setattr(ocs, "_choose_leafs_spines_ocs", plain_choose)
+    placed_plain, report_plain = _ocs_campaign(monkeypatch, seed)
+    assert len(placed) == 300 and placed == placed_plain
+    assert report.jcts == report_plain.jcts
+    assert report.jwts == report_plain.jwts
+    assert report.n_finished == 300
