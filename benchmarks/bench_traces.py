@@ -31,7 +31,7 @@ import tempfile
 
 import numpy as np
 
-from repro.core.jobs import BATCHES, PROFILES
+from repro.core.jobs import BATCHES, DP_MODELS
 
 from .common import timed
 
@@ -68,7 +68,7 @@ def _write_trace(path: str, n: int) -> int:
     arrivals = np.cumsum(rng.exponential(5.0, n))
     gpus = rng.choice([1, 2, 4, 8], n, p=[0.4, 0.3, 0.2, 0.1])
     iters = rng.integers(50, 500, n)
-    models = sorted(PROFILES)
+    models = sorted(DP_MODELS)
     batches = {m: BATCHES[m][0] for m in models}
     with open(path, "w", newline="") as f:
         f.write("job_id,model,num_gpus,batch_size,arrival,num_iters,"

@@ -40,7 +40,8 @@ from .placement import (Placement, PlacementFailure, VirtualClos, commit,
 from .ocs import (RewirePlanner, collect_idle_servers, ocs_release,
                   ocs_vclos_place)
 from .fairshare import maxmin_fair, maxmin_fair_jax, maxmin_fair_numpy
-from .jobs import (BATCHES, PROFILES, Job, ModelProfile, cluster_dataset,
+from .jobs import (BATCHES, DP_MODELS, MODEL_FAMILIES, PROFILES, Job,
+                   ModelProfile, cluster_dataset, megatron_gpt,
                    testbed_dataset, weighted_choice, HELIOS_SIZE_MIX,
                    TPUV4_SIZE_MIX)
 from .events import (EVENT_KINDS, ClusterEvent, frag_index, validate_events)

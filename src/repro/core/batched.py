@@ -49,10 +49,11 @@ import numpy as np
 from repro import obs
 
 from .fairshare import phase_worst_loads
-from .jobs import GBPS, Job
+from .jobs import GBPS, Job, count_group_flows
 from .metrics import MetricsReport, job_metrics
 from .routing import (ECMPRouting, IdealRouting, LinkSpace, SourceRouting,
                       a2a_step_flows, multi_phase_dense_counts)
+from .strategies import check_layout
 from .strategies.builtin import (BestStrategy, ECMPStrategy,
                                  SourceRoutingStrategy)
 
@@ -96,11 +97,13 @@ class _JobPre:
     (model, batch, size, algo) cache.  ``src_r``/``dst_r`` index into the
     job's placed-GPU array (positional equivariance of the collective
     generators); ``nb_arr``/``nar``/``collapse`` mirror the v2 builder's
-    sealed phase bytes including the left-to-right a2a byte sum."""
+    sealed phase bytes including the left-to-right a2a byte sum.  Phases
+    run allreduce (``nar`` of them, coverable), then pipeline (up to
+    ``nbase``), then AlltoAll."""
 
-    __slots__ = ("n", "nar", "nph", "n_a2a_steps", "nb_arr", "c", "beta",
-                 "ii_intra", "ii_fabric", "collapse", "src_r", "dst_r",
-                 "pidx_r", "has_flows")
+    __slots__ = ("n", "nar", "nbase", "nph", "n_a2a_steps", "nb_arr", "c",
+                 "beta", "ii_intra", "ii_fabric", "collapse", "src_r",
+                 "dst_r", "pidx_r", "has_flows", "layered")
 
 
 def _iter_ideal(nb_arr: Optional[np.ndarray], nar: int, c: float,
@@ -122,7 +125,8 @@ def _build_pre(job: Job, link_gbps: float) -> _JobPre:
     n = job.num_gpus
     pre.n = n
     metas, asrc, adst, aidx = job.ar_phase_arrays(np.arange(n))
-    nar = len(metas)
+    nar = sum(1 for k, _b in metas if k == "ar")
+    nbase = len(metas)
     nb: List[float] = [b for _k, b in metas]
     has_a2a = job.profile.alltoall_bytes > 0 and n >= 2
     pre.collapse = False
@@ -136,6 +140,8 @@ def _build_pre(job: Job, link_gbps: float) -> _JobPre:
         else:
             nb.extend([share] * (n - 1))
     pre.nar = nar
+    pre.nbase = nbase
+    pre.layered = job.profile.layered
     pre.nph = len(nb)
     pre.n_a2a_steps = (n - 1) if has_a2a else 0
     pre.nb_arr = np.asarray(nb, dtype=np.float64) if nb else None
@@ -149,7 +155,7 @@ def _build_pre(job: Job, link_gbps: float) -> _JobPre:
         a2s, a2d, a2step = a2a_step_flows(np.arange(n))
         pre.src_r = np.concatenate([asrc, a2s])
         pre.dst_r = np.concatenate([adst, a2d])
-        pre.pidx_r = np.concatenate([aidx, nar + a2step])
+        pre.pidx_r = np.concatenate([aidx, nbase + a2step])
     else:
         pre.src_r, pre.dst_r, pre.pidx_r = asrc, adst, aidx
     pre.has_flows = len(pre.src_r) > 0
@@ -158,12 +164,14 @@ def _build_pre(job: Job, link_gbps: float) -> _JobPre:
 
 # (model, batch, size, algo, link_gbps) -> _JobPre.  Module-level and
 # immutable once built: the inputs are pure functions of the builtin
-# ModelProfile table, so entries are valid across traces and sessions.
+# ModelProfile table (a 3D layout included), so entries are valid across
+# traces and sessions.
 _PRE_CACHE: Dict[tuple, _JobPre] = {}
 
 
-def _pres_for(jobs: Sequence[Job], link_gbps: float) -> List[_JobPre]:
+def _pres_for(jobs: Sequence[Job], spec, strat) -> List[_JobPre]:
     cache = _PRE_CACHE
+    link_gbps = spec.link_gbps
     out = []
     for job in jobs:
         key = (job.model, job.batch_size, job.num_gpus, job.allreduce_algo,
@@ -171,6 +179,10 @@ def _pres_for(jobs: Sequence[Job], link_gbps: float) -> List[_JobPre]:
         pre = cache.get(key)
         if pre is None:
             pre = cache[key] = _build_pre(job, link_gbps)
+        if pre.layered:
+            # _place hands whole servers in rank order: TP groups stay
+            # inside servers wherever they tile them
+            check_layout(strat, spec, job)
         out.append(pre)
     return out
 
@@ -403,7 +415,9 @@ class _BatchedEngine:
         ``(gpus, srv_u, cnt_u)`` — the placement always consists of whole
         -server blocks plus one partial tail, so the unique per-server GPU
         counts come for free (one fancy ``-=``/``+=`` then replaces
-        ``np.add.at`` on both commit and release)."""
+        ``np.add.at`` on both commit and release).  The blocks come in rank
+        order, so a tensor-parallel group of tp | gpus_per_server ranks
+        never spans two servers (``_pres_for`` refuses any other tp)."""
         spec = self.spec
         if self.ft_a[l] < n:
             return None
@@ -468,7 +482,7 @@ class _BatchedEngine:
         if ent is None:
             src = gpus[pre.src_r]
             dst = gpus[pre.dst_r]
-            nphases = pre.nar + pre.n_a2a_steps
+            nphases = pre.nbase + pre.n_a2a_steps
             # two builds, same CSR (both row-major (phase, link), counts
             # identical): the dense bincount wins when the (nphases, nlinks)
             # matrix is small relative to the flow batch, the sort-based
@@ -485,13 +499,13 @@ class _BatchedEngine:
     def _dense_entries(self, lane: _Lane, pre: _JobPre, src, dst,
                        job_id: int, nphases: int):
         # _build_running_v2's fabric branch on the precomputed rank
-        # patterns: one bincount sweep over the whole (AR + a2a) flow
+        # patterns: one bincount sweep over the whole (AR + PP + a2a) flow
         # batch, then the a2a collapse and _attach_dense_phases in CSR
         mat = multi_phase_dense_counts(lane.routing, self.ls, src, dst,
                                        pre.pidx_r, nphases, job_id)
         if pre.collapse:
-            mat = np.vstack([mat[:pre.nar],
-                             mat[pre.nar:].max(axis=0, keepdims=True)])
+            mat = np.vstack([mat[:pre.nbase],
+                             mat[pre.nbase:].max(axis=0, keepdims=True)])
         union = mat.max(axis=0)
         uidx = np.nonzero(union)[0]
         if not len(uidx):
@@ -525,7 +539,7 @@ class _BatchedEngine:
         if pre.collapse:
             # fold the n-1 AlltoAll step rows into one aggregate phase of
             # per-link maxima (v2: mat[nar:].max(axis=0))
-            arm = ph < pre.nar
+            arm = ph < pre.nbase
             al, ac = li[~arm], cnt[~arm]
             if len(al):
                 o2 = np.argsort(al, kind="stable")
@@ -535,7 +549,8 @@ class _BatchedEngine:
             else:
                 cl, cc = al, ac
             ph = np.concatenate([ph[arm],
-                                 np.full(len(cl), pre.nar, dtype=np.int64)])
+                                 np.full(len(cl), pre.nbase,
+                                         dtype=np.int64)])
             li = np.concatenate([li[arm], cl])
             cnt = np.concatenate([cnt[arm], cc])
         pptr = np.searchsorted(ph, np.arange(pre.nph + 1))
@@ -564,8 +579,11 @@ class _BatchedEngine:
         self.t_fin[l, slot] = now + iters_left * iter_ideal / 1.0
         self.order[l, slot] = self.oc_a[l]
         self.oc_a[l] += 1
+        if not intra and obs.recording():
+            count_group_flows(job, gpus, self.spec.gpus_per_leaf)
         if not lane.isolated and not intra and pre.has_flows:
-            entries = self._build_entries(lane, pre, gpus, job.job_id)
+            with obs.span("lanes.entries"):
+                entries = self._build_entries(lane, pre, gpus, job.job_id)
             if entries is not None:
                 (run.uidx, run.uval, run.cat_idx, run.cat_cnt,
                  run.cat_ucnt, run.pptr) = entries
@@ -997,7 +1015,7 @@ def run_lanes(spec, lanes_in: Sequence[tuple],
             if not _routing_qualifies(routing):  # pragma: no cover - guarded
                 raise ValueError(f"strategy {strat.name!r} routing does "
                                  "not qualify for the batched engine")
-            pres = _pres_for(jobs, spec.link_gbps)
+            pres = _pres_for(jobs, spec, strat)
             lanes.append(_Lane(i, spec, ls, list(jobs), pres, routing,
                                strat.isolated))
         engine = _BatchedEngine(spec, lanes, pw_backend=pw_backend)
@@ -1024,7 +1042,7 @@ def try_run_batched(sim, jobs: List[Job],
             or not math.isinf(max_time)
             or sim.running or sim.queue or sim.state.gpu_owner):
         return None
-    pres = _pres_for(jobs, sim.spec.link_gbps)
+    pres = _pres_for(jobs, sim.spec, sim.strategy_obj)
     lane = _Lane(0, sim.spec, sim._ls, list(jobs), pres, sim.routing,
                  sim.isolated)
     engine = _BatchedEngine(sim.spec, [lane])

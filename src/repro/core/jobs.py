@@ -13,6 +13,16 @@ Calibration follows the paper:
     most sensitive, (4) sensitivity is non-linear in the contention level.
   * Job-size mixes for the Helios-based CLUSTER512/2048 datasets (§9.2) and
     the TPUv4-style large-job mix (§9.8, Table 7).
+  * 3D-parallel LLM pretraining jobs (Narayanan et al., SC'21, "Efficient
+    Large-Scale Language Model Training on GPU Clusters Using
+    Megatron-LM", Table 1): a profile's layout (t tensor-parallel GPUs
+    inside a server, p pipeline stages, d = n/(t·p) data-parallel
+    replicas, Megatron's rank order) puts t·p concurrent DP rings and,
+    for p > 1, the stage-boundary sends on the fabric.  The rings are
+    coverable like any allreduce; the pipeline sends are not, like
+    AlltoAll:
+        iter(share) = C + max(0, AR/(bw·share) − β·C) + (A2A + PP)/(bw·share)
+    A profile with t = p = 1 is a plain data-parallel job.
 """
 
 from __future__ import annotations
@@ -23,6 +33,8 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro import obs
 
 from . import traffic
 from .traffic import Flow, Phase
@@ -41,6 +53,57 @@ class ModelProfile:
     alltoall_bytes: float = 0.0   # bytes per GPU per iteration (MoE/DLRM)
     overlap_beta: float = 0.67    # fraction of compute that can hide AR
     allreduce_algos: Tuple[str, ...] = ("ring", "hierarchical_ring", "hd")
+    # -- 3D-parallel layout (1, 1: one data-parallel group over all ranks)
+    tp: int = 1                   # tensor-parallel GPUs, inside one server
+    pp: int = 1                   # pipeline stages
+    boundary_bytes: float = 0.0   # activation bytes of one sample at a
+                                  # stage boundary (seq · hidden · dtype)
+    ref_gpus: int = 0             # GPU count compute_ref is measured at:
+                                  # compute then scales as ref_gpus / n
+                                  # (0: compute per GPU, not rescaled)
+
+    def __post_init__(self) -> None:
+        if self.tp < 1 or self.pp < 1:
+            raise ValueError(f"profile {self.name!r}: tp and pp must be "
+                             f">= 1 (got tp={self.tp}, pp={self.pp})")
+        if self.layered and self.alltoall_bytes:
+            raise ValueError(f"profile {self.name!r}: AlltoAll inside a "
+                             "subgroup of a tp/pp layout is not modelled")
+
+    @property
+    def layered(self) -> bool:
+        """More than one communication group: tp > 1 or pp > 1."""
+        return self.tp * self.pp > 1
+
+    def dp_size(self, n: int) -> int:
+        """Data-parallel replicas d = n / (tp · pp) of an ``n``-GPU job."""
+        group = self.tp * self.pp
+        if n % group:
+            raise ValueError(
+                f"model {self.name!r} has tp={self.tp} x pp={self.pp}: "
+                f"{n} GPUs are not a whole number of model replicas")
+        return n // group
+
+
+def megatron_gpt(name: str, hidden: int, layers: int, tp: int, pp: int,
+                 gpus: int, batch: int, tflops: float, seq: int = 2048,
+                 vocab: int = 51200, dtype_bytes: int = 2) -> ModelProfile:
+    """One GPT row of Narayanan et al., SC'21, Table 1 as a profile.
+
+    Parameters P = 12·l·h²·(1 + 13/(12h) + (V+s)/(12·l·h)); FLOPs per
+    iteration F = 96·B·s·l·h²·(1 + s/(6h) + V/(16·l·h)) (their eq. 3);
+    compute per iteration F / (gpus · achieved FLOP/s).  Gradients and
+    activations are ``dtype_bytes`` wide (bf16); the gradient allreduce
+    starts after the backward pass (β = 0) and is a ring."""
+    h, l, s, v = hidden, layers, seq, vocab
+    params = 12 * l * h * h * (1 + 13 / (12 * h) + (v + s) / (12 * l * h))
+    flops = 96 * batch * s * l * h * h * (1 + s / (6 * h)
+                                          + v / (16 * l * h))
+    return ModelProfile(name, params * dtype_bytes,
+                        flops / (gpus * tflops * 1e12), batch,
+                        overlap_beta=0.0, allreduce_algos=("ring",),
+                        tp=tp, pp=pp, boundary_bytes=s * h * dtype_bytes,
+                        ref_gpus=gpus)
 
 
 # Profiles sized from public model cards; compute_ref ~ V100 throughputs.
@@ -56,11 +119,46 @@ PROFILES: Dict[str, ModelProfile] = {
     "dlrm":      ModelProfile("dlrm", 25e6, 0.015, 256, alltoall_bytes=0.85e9),
 }
 
-# Table 3 mini-batch sets
+#: the data-parallel families above, in their historical order: the pool
+#: the generators draw models from by default
+DP_MODELS: Tuple[str, ...] = tuple(PROFILES)
+
+# Narayanan et al., SC'21, Table 1, the GPT rows whose GPU counts match the
+# paper's Table 7 size mix: (name, hidden, layers, t, p, GPUs, global
+# batch, achieved TFLOP/s per GPU); seq 2048, vocab 51,200
+_GPT_ROWS = (
+    ("gpt-1.7b", 2304, 24, 1, 1, 32, 512, 137.0),
+    ("gpt-3.6b", 3072, 30, 2, 1, 64, 512, 138.0),
+    ("gpt-7.5b", 4096, 36, 4, 1, 128, 512, 142.0),
+    ("gpt-18.4b", 6144, 40, 8, 1, 256, 1024, 135.0),
+    ("gpt-39.1b", 8192, 48, 8, 2, 512, 1536, 138.0),
+)
+PROFILES.update((row[0], megatron_gpt(*row)) for row in _GPT_ROWS)
+
+#: model families drawn by job size: a job of n GPUs runs the family's
+#: row published for n GPUs
+MODEL_FAMILIES: Dict[str, Dict[int, str]] = {
+    "megatron-gpt": {row[5]: row[0] for row in _GPT_ROWS},
+}
+
+# Table 3 mini-batch sets; a GPT row's is its global batch
 BATCHES: Dict[str, Tuple[int, ...]] = {
     "vgg16": (16, 32), "resnet50": (32, 64), "resnet101": (32, 64),
     "bert": (4, 8), "moe": (8, 16), "dlrm": (256, 512),
+    **{row[0]: (row[6],) for row in _GPT_ROWS},
 }
+
+
+def model_for_size(model: str, n: int) -> str:
+    """``model``, or for a :data:`MODEL_FAMILIES` name the family's row
+    for ``n`` GPUs."""
+    rows = MODEL_FAMILIES.get(model)
+    if rows is None:
+        return model
+    if n not in rows:
+        raise ValueError(f"model family {model!r} has no row for {n} GPUs; "
+                         f"it has {sorted(rows)}")
+    return rows[n]
 
 
 @dataclass
@@ -89,15 +187,36 @@ class Job:
     # -- per-iteration time model ------------------------------------------
     def compute_time(self) -> float:
         p = self.profile
-        return p.compute_ref * self.batch_size / p.batch_ref
+        c = p.compute_ref * self.batch_size / p.batch_ref
+        if p.ref_gpus:
+            c = c * p.ref_gpus / self.num_gpus
+        return c
 
     def comm_bytes(self) -> Tuple[float, float]:
-        """(ring-equivalent allreduce bytes per GPU, alltoall bytes per GPU)."""
+        """(ring-equivalent allreduce bytes per GPU, uncoverable bytes per
+        GPU: AlltoAll plus, for a pipelined layout, the stage-boundary
+        sends).  Under a tp/pp layout the allreduce is one DP ring's flow
+        over the d replicas of a 1/(tp·pp) slice of the gradients."""
         p = self.profile
         n = self.num_gpus
+        if p.layered:
+            d = p.dp_size(n)
+            ar = 2.0 * (p.param_bytes / (p.tp * p.pp)) * (d - 1) / d
+            return ar, self.stage_bytes()
         ar = 2.0 * p.param_bytes * (n - 1) / n if n > 1 else 0.0
         a2a = p.alltoall_bytes * (n - 1) / n if n > 1 else 0.0
         return ar, a2a
+
+    def stage_bytes(self) -> float:
+        """Bytes each pipeline send carries per iteration: the boundary
+        activations of a replica's batch / d samples (m micro-batches of
+        b samples, m·b = batch / d whatever b is), split over the tp ranks
+        by Megatron's scatter-gather (0 without a pipeline)."""
+        p = self.profile
+        if p.pp < 2:
+            return 0.0
+        return (self.batch_size / p.dp_size(self.num_gpus)
+                * p.boundary_bytes / p.tp)
 
     def iter_time(self, share: float, link_gbps: float = 100.0) -> float:
         """Iteration latency at a given max-min fair bandwidth share."""
@@ -124,13 +243,22 @@ class Job:
         return self.ar_phases(ranks) + self.a2a_phases(ranks)
 
     def ar_phases(self, ranks: Sequence[int]) -> List[Tuple[str, Phase]]:
-        """The allreduce phases of :meth:`phases` (split out so the
-        simulator can synthesise AlltoAll link loads without materialising
-        every per-step Flow object)."""
+        """The phases of :meth:`phases` other than AlltoAll: the allreduce
+        phases and, under a pipelined layout, the stage-boundary phase
+        tagged "pp" (split out so the simulator can synthesise AlltoAll
+        link loads without materialising every per-step Flow object)."""
         ar, _ = self.comm_bytes()
         p = self.profile
         out: List[Tuple[str, Phase]] = []
         if len(ranks) < 2:
+            return out
+        if p.layered:
+            self._check_layout_algo()
+            if p.dp_size(len(ranks)) > 1:
+                out.append(("ar", traffic.dp_rings(ranks, p.tp, p.pp, ar)))
+            if p.pp > 1:
+                out.append(("pp", traffic.stage_boundary_flows(
+                    ranks, p.tp, p.pp, self.stage_bytes())))
             return out
         n = len(ranks)
         if ar > 0:
@@ -168,6 +296,8 @@ class Job:
         dsts: List[np.ndarray] = []
         n = len(ranks)
         empty = (np.empty(0, dtype=np.int64),) * 3
+        if p.layered and n >= 2:
+            return self._layout_phase_arrays(ranks, ar)
         if n < 2 or ar <= 0:
             return metas, *empty
         r = np.asarray(ranks, dtype=np.int64)
@@ -217,13 +347,65 @@ class Job:
                               [len(s) for s in srcs])
         return metas, np.concatenate(srcs), np.concatenate(dsts), phase_idx
 
+    def _check_layout_algo(self) -> None:
+        if self.allreduce_algo != "ring":
+            raise ValueError(
+                f"job {self.job_id} ({self.model}): a tp/pp layout reduces "
+                f"its gradients over DP rings; allreduce_algo "
+                f"{self.allreduce_algo!r} does not apply")
+
+    def _layout_phase_arrays(self, ranks: Sequence[int], ar: float):
+        """:meth:`ar_phase_arrays` of a tp/pp layout: the t·p DP rings as
+        one "ar" phase, then the stage-boundary sends as one "pp" phase."""
+        self._check_layout_algo()
+        p = self.profile
+        r = np.asarray(ranks, dtype=np.int64)
+        d = p.dp_size(len(r))
+        metas: List[Tuple[str, float]] = []
+        srcs: List[np.ndarray] = []
+        dsts: List[np.ndarray] = []
+        if d > 1:
+            s, t = traffic.dp_ring_index(d, p.tp, p.pp)
+            metas.append(("ar", ar))
+            srcs.append(r[s])
+            dsts.append(r[t])
+        if p.pp > 1:
+            s, t = traffic.stage_boundary_index(d, p.tp, p.pp)
+            metas.append(("pp", self.stage_bytes()))
+            srcs.append(r[s])
+            dsts.append(r[t])
+        if not srcs:
+            return metas, *(np.empty(0, dtype=np.int64),) * 3
+        phase_idx = np.repeat(np.arange(len(srcs), dtype=np.int64),
+                              [len(s) for s in srcs])
+        return metas, np.concatenate(srcs), np.concatenate(dsts), phase_idx
+
     def a2a_phases(self, ranks: Sequence[int]) -> List[Tuple[str, Phase]]:
         """The AlltoAll phases of :meth:`phases` (N-1 pairwise steps)."""
-        _, a2a = self.comm_bytes()
-        if len(ranks) < 2 or a2a <= 0:
+        if len(ranks) < 2 or self.profile.alltoall_bytes <= 0:
             return []
         return [("a2a", ph) for ph in
                 traffic.pairwise_alltoall(ranks, self.profile.alltoall_bytes)]
+
+
+def count_group_flows(job: Job, gpus: Sequence[int],
+                      gpus_per_leaf: int) -> None:
+    """Record one placed job's groups in :mod:`repro.obs`: its cross-leaf
+    flows by group kind (``flows.dp``: DP allreduce flows, ``flows.pp``:
+    pipeline stage-boundary flows) and its DP groups (``groups.dp``: one
+    per (tp, pp) of a layout with more than one replica).  The engines
+    call it only while recording."""
+    metas, src, dst, pidx = job.ar_phase_arrays(gpus)
+    if not metas:
+        return
+    cross = np.bincount(pidx[src // gpus_per_leaf != dst // gpus_per_leaf],
+                        minlength=len(metas))
+    is_pp = np.asarray([k == "pp" for k, _ in metas])
+    obs.count("flows.dp", int(cross[~is_pp].sum()))
+    obs.count("flows.pp", int(cross[is_pp].sum()))
+    p = job.profile
+    if p.dp_size(job.num_gpus) > 1:
+        obs.count("groups.dp", p.tp * p.pp)
 
 
 # ---------------------------------------------------------------------------
@@ -245,7 +427,7 @@ def testbed_dataset(num_jobs: int = 100, seed: int = 0,
     duration scale tuned so Avg.JRT lands in the paper's 70-100 s band and
     the queue stays loaded (Table 4's JWT regime)."""
     rng = np.random.default_rng(seed)
-    models = list(PROFILES)
+    models = list(DP_MODELS)
     jobs: List[Job] = []
     t = 0.0
     for i in range(num_jobs):

@@ -91,19 +91,22 @@ def comm_duty_cycle(job, link_gbps: float = 100.0) -> float:
     *uncoverable* communication (the duty cycle of its network phase).
 
     Uses the same per-iteration model as the simulator at share = 1:
-    allreduce overlaps with β of backward compute, AlltoAll sits on the
-    critical path.  Compute-heavy models (ResNets, large-batch BERT)
-    hide their allreduce entirely → duty 0; AlltoAll models (MoE, DLRM)
-    and small-batch VGG16 expose long windows → duty 0.4-0.8.  Placement
-    scoring only — never fed back into rate resolution.
+    allreduce overlaps with β of backward compute, AlltoAll and the
+    pipeline sends of a tp/pp layout sit on the critical path.
+    Compute-heavy models (ResNets, large-batch BERT) hide their allreduce
+    entirely → duty 0; AlltoAll models (MoE, DLRM) and small-batch VGG16
+    expose long windows → duty 0.4-0.8; the GPT rows (β = 0) expose their
+    DP rings and pipeline sends → duty about 0.05-0.14.  Placement scoring
+    only — never fed back into rate resolution.
     """
     if job.num_gpus <= 1:
         return 0.0
     from .jobs import GBPS                  # local: avoid an import cycle
     c = job.compute_time()
-    ar, a2a = job.comm_bytes()
+    ar, uncoverable = job.comm_bytes()
     bw = link_gbps * GBPS
-    t_comm = max(0.0, ar / bw - job.profile.overlap_beta * c) + a2a / bw
+    t_comm = (max(0.0, ar / bw - job.profile.overlap_beta * c)
+              + uncoverable / bw)
     total = c + t_comm
     return t_comm / total if total > 0 else 0.0
 

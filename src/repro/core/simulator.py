@@ -76,7 +76,7 @@ from .config import ENGINES, SimConfig
 from .events import (FAIL_GPU_OWNER, FAIL_LINK_OWNER, ClusterEvent,
                      frag_index, validate_events)
 from .fairshare import phase_worst_loads
-from .jobs import GBPS, Job
+from .jobs import GBPS, Job, count_group_flows
 from .metrics import MetricsReport, job_metrics
 from .ocs import ocs_release
 from .placement import Placement, PlacementFailure, commit, release
@@ -84,7 +84,7 @@ from .routing import (LinkSpace, SourceRouting, a2a_step_flows,
                       alltoall_link_counts, multi_phase_dense_counts,
                       multi_phase_link_counts)
 from .scheduler import order_queue
-from .strategies import Strategy, strategy_names
+from .strategies import Strategy, check_layout, strategy_names
 from .topology import ClusterSpec, FabricState
 
 NVLINK_SPEEDUP = 12.0  # intra-server fabric vs one NIC (Tbps NVLink vs 100G)
@@ -164,13 +164,13 @@ class _RunningJob:
         c = j.compute_time() / self.compute_scale
         bw_mult = NVLINK_SPEEDUP if self.intra_server else 1.0
         bw = link_gbps * GBPS * bw_mult
-        t_ar = t_a2a = 0.0
+        t_ar = t_a2a = 0.0       # coverable allreduce; uncoverable rest
         for (kind, nbytes, _, _), share in zip(self.phases, shares):
             t = nbytes / (bw * max(share, 1e-9))
-            if kind == "a2a":
-                t_a2a += t
-            else:
+            if kind == "ar":
                 t_ar += t
+            else:
+                t_a2a += t
         return c + max(0.0, t_ar - j.profile.overlap_beta * c) + t_a2a
 
 
@@ -205,7 +205,7 @@ class _RunJobV2:
         self.kinds: List[str] = []
         self.nbytes: List[float] = []
         self.nb_arr: Optional[np.ndarray] = None    # nbytes as float64 array
-        self.nar = 0                                # count of non-a2a phases
+        self.nar = 0                                # count of "ar" phases
         self.cat_idx: Optional[np.ndarray] = None
         self.cat_cnt: Optional[np.ndarray] = None
         self.cat_ucnt: Optional[np.ndarray] = None
@@ -221,8 +221,9 @@ class _RunJobV2:
         # expression; cumsum (not sum) keeps the accumulation strictly
         # left-to-right like the scalar loop — np.sum switches to 8-way
         # unrolled pairwise summation at ≥ 8 elements, which rounds
-        # differently.  AR phases are contiguous before the a2a tail, so
-        # the two slices reproduce the loop's separate accumulators.
+        # differently.  AR phases are contiguous before the uncoverable
+        # tail ("pp", then "a2a"), so the two slices reproduce the loop's
+        # separate accumulators.
         j = self.job
         c = j.compute_time() / self.compute_scale
         bw_mult = NVLINK_SPEEDUP if self.intra_server else 1.0
@@ -363,6 +364,7 @@ class ClusterSimulator:
 
     # -- strategy plumbing: one registry dispatch, no per-strategy branches --
     def _place(self, job: Job):
+        check_layout(self.strategy_obj, self.spec, job)
         # O(1) fast-fail: fewer free GPUs than requested can only ever yield
         # PlacementFailure("gpu") (every strategy needs num_gpus GPUs), so
         # skip the fabric scans — Strategy.place documents this guarantee
@@ -558,6 +560,9 @@ class ClusterSimulator:
     # -- running-set mutation (keeps the link index consistent) -------------
     def _add_running(self, job: Job, placement: Placement) -> None:
         rj = self._build_running(job, placement)
+        if obs.recording() and not rj.intra_server:
+            count_group_flows(job, placement.gpus[:job.num_gpus],
+                              self.spec.gpus_per_leaf)
         rj.last_update = self.now
         rj.t_fin = _finish_time(rj, self.now)
         self.running[job.job_id] = rj
@@ -970,16 +975,16 @@ class ClusterSimulator:
             # AlltoAll steps concatenate into a single (src, dst, phase)
             # batch — one hash/bincount sweep instead of two
             has_a2a = job.profile.alltoall_bytes > 0 and n >= 2
-            nar = len(metas)
+            nbase = len(metas)          # allreduce and pipeline phases
             if has_a2a:
                 a2a_src, a2a_dst, a2a_step = a2a_step_flows(gpus)
-                a2a_idx = nar + a2a_step
+                a2a_idx = nbase + a2a_step
                 src = np.concatenate([asrc, a2a_src])
                 dst = np.concatenate([adst, a2a_dst])
                 pidx = np.concatenate([aidx, a2a_idx])
-                nphases = nar + n - 1
+                nphases = nbase + n - 1
             else:
-                src, dst, pidx, nphases = asrc, adst, aidx, nar
+                src, dst, pidx, nphases = asrc, adst, aidx, nbase
             mat = multi_phase_dense_counts(self.routing, ls, src, dst,
                                            pidx, nphases, job.job_id)
             if mat is None:
@@ -990,8 +995,8 @@ class ClusterSimulator:
                 rj.kinds.append(k)
                 rj.nbytes.append(b)
             if has_a2a and self._append_a2a_meta(rj, job, n):
-                mat = np.vstack([mat[:nar],
-                                 mat[nar:].max(axis=0, keepdims=True)])
+                mat = np.vstack([mat[:nbase],
+                                 mat[nbase:].max(axis=0, keepdims=True)])
         if mat is not None:
             self._attach_dense_phases(rj, mat)
         self._seal_v2(rj, mat)
@@ -1023,7 +1028,7 @@ class ClusterSimulator:
         share rule as ``_build_running`` (bitwise twin)."""
         if rj.kinds:
             rj.nb_arr = np.asarray(rj.nbytes, dtype=np.float64)
-            rj.nar = sum(1 for k in rj.kinds if k != "a2a")
+            rj.nar = sum(1 for k in rj.kinds if k == "ar")
         spec = self.spec
         n = len(rj.kinds)
         if rj.intra_server or not spec.is_hetero:
@@ -1085,6 +1090,9 @@ class ClusterSimulator:
 
     def _add_running_v2(self, job: Job, placement: Placement) -> None:
         rj = self._build_running_v2(job, placement)
+        if obs.recording() and not rj.intra_server:
+            count_group_flows(job, placement.gpus[:job.num_gpus],
+                              self.spec.gpus_per_leaf)
         rj.last_update = self.now
         rj.t_fin = _finish_time(rj, self.now)
         rj.order = self._order_counter

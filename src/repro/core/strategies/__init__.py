@@ -74,7 +74,7 @@ from ..topology import ClusterSpec
 
 __all__ = [
     "Strategy", "register_strategy", "unregister_strategy", "get_strategy",
-    "strategy_names", "registered_strategies",
+    "strategy_names", "registered_strategies", "check_layout",
 ]
 
 
@@ -119,6 +119,11 @@ class Strategy:
     #: queueing policies this strategy supports (subset of
     #: :data:`repro.core.scheduler.QUEUE_POLICIES`)
     queue_policies: Tuple[str, ...] = QUEUE_POLICIES
+    #: placement hands whole servers in rank order, so every tensor
+    #: -parallel group of a tp/pp layout stays inside one server; a
+    #: strategy without it refuses jobs with such a layout
+    #: (:func:`check_layout`)
+    keeps_tp_groups: bool = False
 
     def make_routing(self, spec: ClusterSpec, seed: int) -> Routing:
         """Fresh routing instance for one simulation run (may be stateful —
@@ -180,6 +185,26 @@ def register_strategy(strategy=None, *, replace: bool = False):
     if strategy is None:
         return _register
     return _register(strategy)
+
+
+def check_layout(strategy: Strategy, spec: ClusterSpec, job: Job) -> None:
+    """Refuse a job whose profile has a tp/pp layout (tp > 1 or pp > 1)
+    that ``strategy`` cannot place: one whose placement does not keep
+    tensor-parallel groups inside servers, or a fabric whose servers a TP
+    group cannot tile.  A plain data-parallel job always passes."""
+    p = job.profile
+    if not p.layered:
+        return
+    if not strategy.keeps_tp_groups:
+        raise ValueError(
+            f"strategy {strategy.name!r} cannot place job {job.job_id} "
+            f"({job.model}, tp={p.tp}, pp={p.pp}): its placement does not "
+            f"keep tensor-parallel groups inside servers; use one of "
+            f"{[s for s, st in _REGISTRY.items() if st.keeps_tp_groups]}")
+    if spec.gpus_per_server % p.tp:
+        raise ValueError(
+            f"job {job.job_id} ({job.model}): tensor-parallel groups of "
+            f"{p.tp} GPUs do not tile servers of {spec.gpus_per_server}")
 
 
 def unregister_strategy(name: str) -> None:

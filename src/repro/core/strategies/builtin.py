@@ -24,7 +24,10 @@ from . import Strategy, register_strategy
 def locality_packed_place(ctx, job_id: int, num_gpus: int):
     """Shared baseline placement: best-fit one server (stage 0), else one
     leaf in whole idle servers (stage 1), else whole idle servers across
-    leafs, fewest-idle first.  Public building block for plugins."""
+    leafs, fewest-idle first.  Public building block for plugins.  A job
+    of more than one server gets whole servers in rank order, so rank
+    blocks of any divisor of ``gpus_per_server`` (tensor-parallel groups)
+    never span two servers."""
     state, spec = ctx.state, ctx.spec
     if num_gpus <= spec.gpus_per_server:
         p = stage0_server(state, job_id, num_gpus)
@@ -46,6 +49,7 @@ class BestStrategy(Strategy):
     description = "ideal single big switch: contention-free upper bound"
     isolated = True
     supports_migration = True
+    keeps_tp_groups = True
 
     def make_routing(self, spec, seed):
         return IdealRouting(spec)
@@ -58,6 +62,7 @@ class BestStrategy(Strategy):
 class SourceRoutingStrategy(Strategy):
     name = "sr"
     description = "static per-leaf source routing, locality-packed, no isolation"
+    keeps_tp_groups = True
 
     def place(self, ctx, job_id, num_gpus, job=None):
         return locality_packed_place(ctx, job_id, num_gpus)
@@ -67,6 +72,7 @@ class SourceRoutingStrategy(Strategy):
 class ECMPStrategy(Strategy):
     name = "ecmp"
     description = "5-tuple-hash routing per flow: the hash-collision baseline"
+    keeps_tp_groups = True
 
     def make_routing(self, spec, seed):
         return ECMPRouting(spec, seed=seed)

@@ -55,7 +55,7 @@ from dataclasses import dataclass, field
 from typing import (Dict, Iterable, Iterator, List, Optional, Sequence,
                     Tuple, Union)
 
-from .jobs import BATCHES, PROFILES, Job
+from .jobs import BATCHES, DP_MODELS, PROFILES, Job
 from .workloads import (ALLREDUCE_ALGOS, TRACE_FIELDS, WorkloadSpec,
                         job_from_trace_row, parse_trace_time)
 
@@ -93,9 +93,10 @@ class JobIdInterner:
         return dict(self._ids)
 
 
-#: model-mix used when a trace carries no model column, in stable name
-#: order (dict order would silently re-map every job if PROFILES grew)
-_MODEL_POOL: Tuple[str, ...] = tuple(sorted(PROFILES))
+#: model-mix used when a trace carries no model column: the data-parallel
+#: families in stable name order (drawing from all of PROFILES would
+#: re-map every job whenever a profile is added)
+_MODEL_POOL: Tuple[str, ...] = tuple(sorted(DP_MODELS))
 
 
 def stable_model_for(raw_id: object) -> str:

@@ -136,6 +136,47 @@ def pipeline_p2p(ranks: Sequence[int], nbytes: float,
 
 
 # ---------------------------------------------------------------------------
+# 3D-parallel layouts (Megatron-LM rank order: TP innermost, then DP, PP
+# outermost, so rank r = pp·(d·t) + dp·t + tp)
+# ---------------------------------------------------------------------------
+
+def dp_ring_index(d: int, t: int, p: int):
+    """``(src, dst)`` rank indices of the t·p concurrent data-parallel
+    rings of a (t, p, d) layout: one ring per (tp, pp) over its d ranks,
+    each rank sending to the rank one DP step ahead.  Sources come out in
+    rank order; with t = p = 1 this is the plain ring over all ranks."""
+    n = d * t * p
+    src = np.arange(n, dtype=np.int64)
+    dp = (src // t) % d
+    return src, src + (((dp + 1) % d) - dp) * t
+
+
+def stage_boundary_index(d: int, t: int, p: int):
+    """``(src, dst)`` rank indices of a (t, p, d) layout's pipeline sends:
+    at every stage boundary each (dp, tp) rank sends activations to its
+    twin in the next stage, and the twin sends gradients back.  Forward
+    flows first, then backward, both in rank order."""
+    fwd = np.arange((p - 1) * d * t, dtype=np.int64)
+    bwd = fwd + d * t
+    return np.concatenate([fwd, bwd]), np.concatenate([bwd, fwd])
+
+
+def dp_rings(ranks: Sequence[int], t: int, p: int, nbytes: float) -> Phase:
+    """The DP rings of :func:`dp_ring_index` over GPU ids ``ranks``, as one
+    concurrent phase whose flows each carry ``nbytes``."""
+    src, dst = dp_ring_index(len(ranks) // (t * p), t, p)
+    return [Flow(ranks[a], ranks[b], nbytes) for a, b in zip(src, dst)]
+
+
+def stage_boundary_flows(ranks: Sequence[int], t: int, p: int,
+                         nbytes: float) -> Phase:
+    """The pipeline sends of :func:`stage_boundary_index` over GPU ids
+    ``ranks``, as one concurrent phase whose flows each carry ``nbytes``."""
+    src, dst = stage_boundary_index(len(ranks) // (t * p), t, p)
+    return [Flow(ranks[a], ranks[b], nbytes) for a, b in zip(src, dst)]
+
+
+# ---------------------------------------------------------------------------
 # Double binary tree (§5.3 "does not follow the pattern" example)
 # ---------------------------------------------------------------------------
 

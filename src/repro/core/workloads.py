@@ -31,8 +31,8 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from .events import ClusterEvent
-from .jobs import (BATCHES, HELIOS_SIZE_MIX, PROFILES, TPUV4_SIZE_MIX, Job,
-                   weighted_choice)
+from .jobs import (BATCHES, DP_MODELS, HELIOS_SIZE_MIX, PROFILES,
+                   TPUV4_SIZE_MIX, Job, model_for_size, weighted_choice)
 from .topology import ClusterSpec
 
 SizeMix = Sequence[Tuple[int, float]]
@@ -57,12 +57,16 @@ class WorkloadSpec:
     smaller λ ⇒ higher offered load. ``deadline_slack`` — when set to a
     ``(lo, hi)`` pair — assigns each job a deadline of
     ``arrival + ideal_runtime * U(lo, hi)`` for EDF experiments (§9.7).
+    ``models`` names profiles or model families
+    (:data:`repro.core.jobs.MODEL_FAMILIES`): ``("megatron-gpt",)`` over
+    the ``"tpuv4"`` size mix draws the Megatron-LM GPT row published for
+    each job's GPU count, a 3D-parallel LLM pretraining fleet.
     """
 
     num_jobs: int = 1000
     mean_interarrival: float = 120.0
     size_mix: Union[str, Tuple[Tuple[int, float], ...]] = "helios"
-    models: Tuple[str, ...] = tuple(PROFILES)
+    models: Tuple[str, ...] = DP_MODELS
     iters_log_mean: float = 8.8
     iters_log_sigma: float = 1.1
     min_iters: int = 50
@@ -121,9 +125,11 @@ def generate_trace(spec: WorkloadSpec) -> List[Job]:
         n = int(weighted_choice(rng, sizes, probs))
         if spec.max_gpus:
             n = min(n, spec.max_gpus)
-        model = models[rng.integers(len(models))]
+        model = model_for_size(models[rng.integers(len(models))], n)
         batch = int(BATCHES[model][rng.integers(len(BATCHES[model]))])
         algo = ALLREDUCE_ALGOS[rng.integers(len(ALLREDUCE_ALGOS))]
+        if algo not in PROFILES[model].allreduce_algos:
+            algo = PROFILES[model].allreduce_algos[0]
         iters = int(rng.lognormal(mean=spec.iters_log_mean,
                                   sigma=spec.iters_log_sigma))
         t += rng.exponential(spec.mean_interarrival)

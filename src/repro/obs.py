@@ -30,11 +30,18 @@ Spans in the program (each one's name says where it is):
 * ``rate.solve``: one ``phase_worst_loads`` call;
 * ``place``: one strategy ``place`` call of the v1/v2 engines, tagged
   ``fail`` when it returns a ``PlacementFailure``;
-* ``ocs.findclos``: the virtual-Clos search of OCS placement.
+* ``ocs.findclos``: the virtual-Clos search of OCS placement;
+* ``lanes.entries``: the lane engine's link-entry build of one placed
+  job (routing its flows onto links, or a cache hit), inside
+  ``lanes.schedule``.
 
 Counters: ``ocs.candidates`` (leaf x spine factorisations the OCS search
 tried), ``ocs.budget`` (port budgets the OCS search counted, one per
-search, shared by its candidates) and ``jax.compiles``.
+search, shared by its candidates), ``jax.compiles``, and per job placed
+across servers by the lane engine or v1/v2: ``flows.dp`` and ``flows.pp``
+(its cross-leaf flows, source and destination under different leafs, of
+the data-parallel allreduce and of the pipeline stage boundaries) and
+``groups.dp`` (its DP groups: one ring per (tp, pp) of a 3D layout).
 
 Recording follows one thread: spans opened from several threads at once
 nest wrongly.  Spans of process-pool workers are not recorded.  This
@@ -127,6 +134,12 @@ def tag(value: str) -> None:
     st = _active
     if st is not None and st.open:
         st.rec[st.open[-1] + _TAG] = st.intern(value)
+
+
+def recording() -> bool:
+    """Whether recording is on: callers skip work that only feeds a
+    counter."""
+    return _active is not None
 
 
 def count(name: str, n: int = 1) -> None:

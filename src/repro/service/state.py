@@ -47,6 +47,7 @@ from ..core.metrics import MetricsReport
 from ..core.placement import PlacementFailure
 from ..core.runtime import LineJournal
 from ..core.simulator import ClusterSimulator
+from ..core.strategies import check_layout
 from ..core.topology import ClusterSpec
 
 __all__ = ["LiveCluster", "ServiceLog", "RecordingSimulator",
@@ -309,6 +310,9 @@ class LiveCluster:
                   arrival=self.sim.now if arrival is None else arrival,
                   num_iters=num_iters, allreduce_algo=allreduce_algo,
                   deadline=deadline)
+        # refuse a tp/pp layout the strategy cannot place now, not when
+        # the event loop first tries to place it
+        check_layout(self.sim.strategy_obj, self.sim.spec, job)
         return job
 
     def admission(self, tenant: str, num_gpus: int) -> Tuple[bool, str]:

@@ -437,7 +437,9 @@ def test_flow_cap_below_bottleneck_is_uniform():
 
 def test_comm_duty_cycle_range_and_degenerate():
     for model, prof in PROFILES.items():
-        j = Job(job_id=0, model=model, num_gpus=8,
+        # a 3D-parallel GPT row at its published GPU count (its layout
+        # needs whole model replicas), every other profile at 8
+        j = Job(job_id=0, model=model, num_gpus=prof.ref_gpus or 8,
                 batch_size=prof.batch_ref, arrival=0.0, num_iters=1)
         assert 0.0 <= comm_duty_cycle(j) < 1.0
     single = Job(job_id=0, model="resnet50", num_gpus=1, batch_size=32,

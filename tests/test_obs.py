@@ -20,7 +20,8 @@ from repro.core.topology import CLUSTER512, CLUSTER512_OCS
 from repro.core.workloads import WorkloadSpec, generate_trace
 
 LANE_SPANS = ("campaign.run", "lanes.prepare", "lanes.run",
-              "lanes.schedule", "lanes.rate", "lanes.report", "rate.solve")
+              "lanes.schedule", "lanes.entries", "lanes.rate",
+              "lanes.report", "rate.solve")
 OCS_SPANS = ("campaign.run", "place", "ocs.findclos")
 
 
@@ -169,6 +170,55 @@ def test_ocs_counts_agree_with_outside_wrappers(monkeypatch):
     assert c["ocs.budget"] == s["ocs.findclos"]["count"]
     assert c["ocs.candidates"] >= c["ocs.budget"]
     assert "rate.solve" not in s       # ocs-vclos is isolated
+
+
+def _gpt_lanes():
+    """The lane grid over a Megatron-LM fleet: the Table 7 size mix, each
+    job the GPT row published for its size (tp/pp layouts)."""
+    grid = CampaignGrid(strategies=("best", "sr", "ecmp"),
+                        schedulers=("fifo",), loads=(600.0,), seeds=(0,))
+    trace = generate_trace(WorkloadSpec(
+        num_jobs=30, mean_interarrival=600.0, size_mix="tpuv4",
+        models=("megatron-gpt",), seed=3))
+    return run_campaign(CLUSTER512, grid, trace=trace, engine="batched")
+
+
+@pytest.mark.parametrize("run", [_lanes, _gpt_lanes], ids=["dp", "gpt"])
+def test_group_counters_agree_with_outside_wrappers(monkeypatch, run):
+    """``lanes.entries`` counts the entry builds; ``flows.dp``,
+    ``flows.pp`` and ``groups.dp`` count the cross-leaf flows and DP
+    groups of the Flow-level phases of every job placed across
+    servers."""
+    calls = {}
+    _counting(monkeypatch, _BatchedEngine, "_build_entries", calls,
+              "entries")
+    placed = []
+    orig = _BatchedEngine._add_running
+
+    def spy(self, l, lane, jidx, job, gpus, srv_u, cnt_u):
+        if len(srv_u) > 1:
+            placed.append((job, list(gpus)))
+        return orig(self, l, lane, jidx, job, gpus, srv_u, cnt_u)
+
+    monkeypatch.setattr(_BatchedEngine, "_add_running", spy)
+    obs.enable()
+    run()
+    snap = obs.snapshot()
+    obs.disable()
+    gpl = CLUSTER512.gpus_per_leaf
+    want = {"flows.dp": 0, "flows.pp": 0, "groups.dp": 0}
+    for job, gpus in placed:
+        for kind, phase in job.ar_phases(gpus):
+            key = "flows.pp" if kind == "pp" else "flows.dp"
+            want[key] += sum(f.src // gpl != f.dst // gpl for f in phase)
+        prof = job.profile
+        if job.num_gpus > prof.tp * prof.pp:
+            want["groups.dp"] += prof.tp * prof.pp
+    assert calls["entries"] > 0 and want["flows.dp"] > 0
+    assert (want["flows.pp"] > 0) == (run is _gpt_lanes)
+    assert snap["spans"]["lanes.entries"]["count"] == calls["entries"]
+    for name, n in want.items():
+        assert snap["counters"].get(name, 0) == n, name
 
 
 def test_every_span_shows_on_the_host_plane_of_a_profiler_trace(tmp_path):
