@@ -89,6 +89,12 @@ class RewirePlanner:
     (unreserved, unpinned) capacity.  All moves are collected and applied
     atomically by the caller via ``apply``.  A ``budget`` of the same,
     unchanged fabric saves recounting its spare channels.
+
+    Lookups walk only the ports of the leaf and spine at hand, through the
+    shape's port index (:class:`~repro.core.topology.OCSPorts`), against
+    two structures every move keeps current: ``_wired[k]``, OCS k's
+    circuits from the spine side, and ``_taken``, the leaf ports held by
+    cross-connects, live or planned.
     """
 
     def __init__(self, state: FabricState,
@@ -102,30 +108,26 @@ class RewirePlanner:
         spare = budget.spare if budget is not None else state.free_capacity()
         self.spare = [row[:] for row in spare]
         self.moves: List[Tuple[int, int, int]] = []     # (k, leaf_port, spine_port)
-        self.unwired: List[Tuple[int, int]] = []        # (k, leaf_port) — for xconn
-        ports = ocs_ports(self.spec)
-        self._lports = [p.leaf_ports for p in ports]
-        self._sports = [p.spine_ports for p in ports]
+        self.unwired: List[Tuple[int, int, int]] = []   # (k, leaf_port, old spine port)
+        self._ports = ocs_ports(self.spec)
+        self._wired = [{sp: lp for lp, sp in c.items()} for c in self.circuits]
+        self._taken = set(state.xconn_owner)
 
     # -- lookups over the working copy --------------------------------------
-    def _endpoints(self, k: int):
-        return self._lports[k], self._sports[k]
-
     def _movable_leaf_port(self, k: int, leaf: int,
                            avoid_spine: Optional[int] = None) -> Optional[int]:
-        lports, sports = self._endpoints(k)
-        taken = {(kk, pp) for (kk, pp, *_rest) in self.unwired}
-        taken |= set(self.state.xconn_owner)
-        for lp, (n, _) in enumerate(lports):
-            if n != leaf or (k, lp) in taken:
+        ports, circuits = self._ports[k], self.circuits[k]
+        row, taken = self.spare[leaf], self._taken
+        for lp in ports.leaf_ports_of[leaf]:
+            if (k, lp) in taken:
                 continue
-            sp = self.circuits[k].get(lp)
+            sp = circuits.get(lp)
             if sp is None:
                 return lp  # unwired: free to use
-            m, _ = sports[sp]
+            m = ports.spine_of[sp]
             if m == avoid_spine:
                 continue  # already on the target spine — moving it is a no-op
-            if self.spare[n][m] > 0:
+            if row[m] > 0:
                 return lp
         return None
 
@@ -134,16 +136,14 @@ class RewirePlanner:
         """A spine-side port on OCS k that is unwired (preferred — no
         eviction) or ends a movable circuit.  Never evicts a circuit from
         ``for_leaf`` itself — that would undo the channel being built."""
-        lports, sports = self._endpoints(k)
-        wired = {sp: lp for lp, sp in self.circuits[k].items()}
+        wired, leaf_of = self._wired[k], self._ports[k].leaf_of
         evictable = None
-        for sp, (m, _) in enumerate(sports):
-            if m != spine:
-                continue
-            if sp not in wired:
+        for sp in self._ports[k].spine_ports_of[spine]:
+            lp = wired.get(sp)
+            if lp is None:
                 return sp
             if evictable is None:
-                n2, _ = lports[wired[sp]]
+                n2 = leaf_of[lp]
                 if n2 != for_leaf and self.spare[n2][spine] > 0:
                     evictable = sp
         return evictable
@@ -152,43 +152,45 @@ class RewirePlanner:
     def _headroom(self, k: int, leaf: int, spine: int) -> int:
         """How much slack OCS k has for a (leaf, spine) circuit: counts of
         movable leaf ports × available spine ports (0 when either missing)."""
-        lports, sports = self._endpoints(k)
-        taken = {(kk, pp) for (kk, pp, *_r) in self.unwired}
-        taken |= set(self.state.xconn_owner)
+        ports, circuits = self._ports[k], self.circuits[k]
+        spine_of, spare, taken = ports.spine_of, self.spare, self._taken
+        row = spare[leaf]
         nl = 0
-        for lp, (n, _) in enumerate(lports):
-            if n != leaf or (k, lp) in taken:
+        for lp in ports.leaf_ports_of[leaf]:
+            if (k, lp) in taken:
                 continue
-            sp = self.circuits[k].get(lp)
+            sp = circuits.get(lp)
             if sp is None:
                 nl += 1
                 continue
-            m, _ = sports[sp]
-            if m != spine and self.spare[n][m] > 0:
+            m = spine_of[sp]
+            if m != spine and row[m] > 0:
                 nl += 1
         if nl == 0:
             return 0
-        wired = {s_: l_ for l_, s_ in self.circuits[k].items()}
+        wired, leaf_of = self._wired[k], ports.leaf_of
         ns = 0
-        for sp, (m, _) in enumerate(sports):
-            if m != spine:
-                continue
-            if sp not in wired:
+        for sp in ports.spine_ports_of[spine]:
+            lp = wired.get(sp)
+            if lp is None:
                 ns += 2  # unwired spine port: cheapest (no eviction)
                 continue
-            n2, _ = lports[wired[sp]]
-            if n2 != leaf and self.spare[n2][spine] > 0:
+            n2 = leaf_of[lp]
+            if n2 != leaf and spare[n2][spine] > 0:
                 ns += 1
         return min(nl, ns) if ns else 0
 
     def add_channel(self, leaf: int, spine: int) -> bool:
         """Create one extra channel leaf→spine, choosing the OCS with the
         most remaining slack (load-balances circuits across OCSes so later
-        demands don't starve)."""
-        order = sorted(range(self.spec.num_ocs),
-                       key=lambda k: -self._headroom(k, leaf, spine))
+        demands don't starve).  Nothing moves before a move returns, so
+        each OCS's headroom is taken once."""
+        obs.count("ocs.channels")
+        head = [self._headroom(k, leaf, spine)
+                for k in range(self.spec.num_ocs)]
+        order = sorted(range(self.spec.num_ocs), key=lambda k: -head[k])
         for k in order:
-            if self._headroom(k, leaf, spine) <= 0:
+            if head[k] <= 0:
                 break
             lp = self._movable_leaf_port(k, leaf, avoid_spine=spine)
             if lp is None:
@@ -196,30 +198,30 @@ class RewirePlanner:
             sp = self._free_spine_port(k, spine, for_leaf=leaf)
             if sp is None:
                 continue
-            lports, sports = self._endpoints(k)
-            wired = {s_: l_ for l_, s_ in self.circuits[k].items()}
+            ports, circuits = self._ports[k], self.circuits[k]
+            wired, spare = self._wired[k], self.spare
+            lp2 = wired.get(sp)
             # 1. detach lp from its old spine port (frees old channel)
-            old_sp = self.circuits[k].pop(lp, None)
+            old_sp = circuits.pop(lp, None)
             if old_sp is not None:
-                m_old, _ = sports[old_sp]
-                n, _ = lports[lp]
-                self.spare[n][m_old] -= 1  # channel disappears
+                del wired[old_sp]
+                spare[leaf][ports.spine_of[old_sp]] -= 1  # channel disappears
             # 2. evict the circuit currently on sp, if any — rehome its leaf
             #    port onto lp's old spine port (classic 2-swap)
-            if sp in wired and wired[sp] != lp:
-                lp2 = wired[sp]
-                n2, _ = lports[lp2]
-                self.spare[n2][spine] -= 1
-                del self.circuits[k][lp2]
+            if lp2 is not None and lp2 != lp:
+                n2 = ports.leaf_of[lp2]
+                spare[n2][spine] -= 1
+                del circuits[lp2]
+                del wired[sp]
                 if old_sp is not None:
-                    m_old, _ = sports[old_sp]
-                    self.circuits[k][lp2] = old_sp
-                    self.spare[n2][m_old] += 1
+                    circuits[lp2] = old_sp
+                    wired[old_sp] = lp2
+                    spare[n2][ports.spine_of[old_sp]] += 1
                     self.moves.append((k, lp2, old_sp))
             # 3. wire lp -> sp
-            self.circuits[k][lp] = sp
-            n, _ = lports[lp]
-            self.spare[n][spine] += 1
+            circuits[lp] = sp
+            wired[sp] = lp
+            spare[leaf][spine] += 1
             self.moves.append((k, lp, sp))
             return True
         return False
@@ -230,14 +232,15 @@ class RewirePlanner:
         Pins created channels so later swaps cannot cannibalise them.
         Bounded by the total port count — a livelock guard, not a budget.
         """
-        guard = 4 * self.spec.num_leafs * self.spec.uplinks_per_leaf
-        for (n, m), cnt in sorted(need.items()):
-            while self.spare[n][m] < cnt:
-                guard -= 1
-                if guard <= 0 or not self.add_channel(n, m):
-                    return False
-            self.spare[n][m] -= cnt  # pin
-        return True
+        with obs.span("ocs.rewire"):
+            guard = 4 * self.spec.num_leafs * self.spec.uplinks_per_leaf
+            for (n, m), cnt in sorted(need.items()):
+                while self.spare[n][m] < cnt:
+                    guard -= 1
+                    if guard <= 0 or not self.add_channel(n, m):
+                        return False
+                self.spare[n][m] -= cnt  # pin
+            return True
 
     def take_xconn(self, leaf_a: int, leaf_b: int, count: int) -> bool:
         """Unwire `count` movable ports on each of two leafs sharing an OCS
@@ -254,6 +257,7 @@ class RewirePlanner:
                     orig = self.circuits[k].get(p)
                     self._unwire(k, p)
                     self.unwired.append((k, p, -1 if orig is None else orig))
+                    self._taken.add((k, p))
                 done += 1
             if done >= count:
                 return True
@@ -262,10 +266,9 @@ class RewirePlanner:
     def _unwire(self, k: int, lp: int) -> None:
         sp = self.circuits[k].pop(lp, None)
         if sp is not None:
-            lports, sports = self._endpoints(k)
-            n, _ = lports[lp]
-            m, _ = sports[sp]
-            self.spare[n][m] -= 1
+            del self._wired[k][sp]
+            ports = self._ports[k]
+            self.spare[ports.leaf_of[lp]][ports.spine_of[sp]] -= 1
 
     def apply(self) -> None:
         """Write the planned circuit layout back to the live OCS."""

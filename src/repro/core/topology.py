@@ -247,12 +247,16 @@ class OCSPorts:
     ``leaf_ports[lp]`` is the leaf-side port's ``(leaf n, uplink j)`` and
     ``spine_ports[sp]`` the spine-side port's ``(spine m, downlink i)``;
     ``leaf_of`` and ``spine_of`` hold the first element of each alone.
+    ``leaf_ports_of[n]`` and ``spine_ports_of[m]`` are the reverse index:
+    the local port ids of leaf n and of spine m on this OCS, ascending.
     """
 
     leaf_ports: Tuple[Tuple[int, int], ...]
     spine_ports: Tuple[Tuple[int, int], ...]
     leaf_of: Tuple[int, ...]
     spine_of: Tuple[int, ...]
+    leaf_ports_of: Tuple[Tuple[int, ...], ...]
+    spine_ports_of: Tuple[Tuple[int, ...], ...]
 
 
 def ocs_ports(spec: ClusterSpec) -> Tuple[OCSPorts, ...]:
@@ -275,8 +279,14 @@ def _ocs_ports(num_leafs: int, num_spines: int, uplinks: int,
                        for j in range(k, uplinks, num_ocs))
         sports = tuple((m, i) for m in range(num_spines)
                        for i in range(k, downlinks, num_ocs))
-        out.append(OCSPorts(lports, sports, tuple(n for n, _ in lports),
-                            tuple(m for m, _ in sports)))
+        leaf_of = tuple(n for n, _ in lports)
+        spine_of = tuple(m for m, _ in sports)
+        out.append(OCSPorts(
+            lports, sports, leaf_of, spine_of,
+            tuple(tuple(lp for lp, n in enumerate(leaf_of) if n == leaf)
+                  for leaf in range(num_leafs)),
+            tuple(tuple(sp for sp, m in enumerate(spine_of) if m == spine)
+                  for spine in range(num_spines))))
     return tuple(out)
 
 

@@ -31,16 +31,21 @@ Spans in the program (each one's name says where it is):
 * ``place``: one strategy ``place`` call of the v1/v2 engines, tagged
   ``fail`` when it returns a ``PlacementFailure``;
 * ``ocs.findclos``: the virtual-Clos search of OCS placement;
+* ``ocs.rewire``: one ``RewirePlanner.ensure`` call, the circuit swaps
+  that make a placement's channels, inside ``ocs.findclos`` or stage 2's
+  ``place``;
 * ``lanes.entries``: the lane engine's link-entry build of one placed
   job (routing its flows onto links, or a cache hit), inside
   ``lanes.schedule``.
 
 Counters: ``ocs.candidates`` (leaf x spine factorisations the OCS search
 tried), ``ocs.budget`` (port budgets the OCS search counted, one per
-search, shared by its candidates), ``jax.compiles``, and per job placed
-across servers by the lane engine or v1/v2: ``flows.dp`` and ``flows.pp``
-(its cross-leaf flows, source and destination under different leafs, of
-the data-parallel allreduce and of the pipeline stage boundaries) and
+search, shared by its candidates), ``ocs.channels`` (channels the OCS
+search asked the rewire planner for, one per ``add_channel`` call),
+``jax.compiles``, and per job placed across servers by the lane engine or
+v1/v2: ``flows.dp`` and ``flows.pp`` (its cross-leaf flows, source and
+destination under different leafs, of the data-parallel allreduce and of
+the pipeline stage boundaries) and
 ``groups.dp`` (its DP groups: one ring per (tp, pp) of a 3D layout).
 
 Recording follows one thread: spans opened from several threads at once

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import batched
+from repro.core import batched, ocs
 from repro.core.batched import _BatchedEngine
 from repro.core.campaign import CampaignGrid, run_campaign
 from repro.core.placement import PlacementFailure
@@ -22,7 +22,7 @@ from repro.core.workloads import WorkloadSpec, generate_trace
 LANE_SPANS = ("campaign.run", "lanes.prepare", "lanes.run",
               "lanes.schedule", "lanes.entries", "lanes.rate",
               "lanes.report", "rate.solve")
-OCS_SPANS = ("campaign.run", "place", "ocs.findclos")
+OCS_SPANS = ("campaign.run", "place", "ocs.findclos", "ocs.rewire")
 
 
 @pytest.fixture(autouse=True)
@@ -45,10 +45,10 @@ def _lanes():
                         engine="batched")
 
 
-def _ocs():
+def _ocs(num_jobs=60):
     grid = CampaignGrid(strategies=("ocs-vclos",), schedulers=("fifo",),
                         loads=(60.0,), seeds=(0,))
-    return run_campaign(CLUSTER512, grid, trace=_trace(60, 256, seed=5),
+    return run_campaign(CLUSTER512, grid, trace=_trace(num_jobs, 256, seed=5),
                         engine="batched", ocs_spec=CLUSTER512_OCS)
 
 
@@ -170,6 +170,37 @@ def test_ocs_counts_agree_with_outside_wrappers(monkeypatch):
     assert c["ocs.budget"] == s["ocs.findclos"]["count"]
     assert c["ocs.candidates"] >= c["ocs.budget"]
     assert "rate.solve" not in s       # ocs-vclos is isolated
+
+
+def test_rewire_counts_agree_with_outside_wrappers(monkeypatch):
+    """``ocs.channels`` counts add_channel calls, ``ocs.rewire`` one span
+    per ``ensure``, opened inside the search or stage 2's ``place``."""
+    calls = {}
+    _counting(monkeypatch, ocs.RewirePlanner, "add_channel", calls, "add")
+    _counting(monkeypatch, ocs.RewirePlanner, "ensure", calls, "ensure")
+    obs.enable()
+    _ocs(120)
+    snap = obs.snapshot()
+    st = obs._store
+    obs.disable()
+    assert calls["add"] > 0 and calls["ensure"] > 0
+    assert snap["counters"]["ocs.channels"] == calls["add"]
+    assert snap["spans"]["ocs.rewire"]["count"] == calls["ensure"]
+    names = st.rec[obs._NAME::obs._FIELDS]
+    parents = st.rec[obs._PARENT::obs._FIELDS]
+    rewire = st.ids["ocs.rewire"]
+    outer = [st.names[names[parents[i] // obs._FIELDS]]
+             for i, name in enumerate(names) if name == rewire]
+    assert len(outer) == calls["ensure"]
+    assert "ocs.findclos" in outer
+    assert set(outer) <= {"ocs.findclos", "place"}
+
+
+def test_off_an_ocs_run_keeps_nothing():
+    obs.enable()
+    obs.disable()
+    _ocs(120)
+    assert obs.snapshot() == {"spans": {}, "counters": {obs.COMPILES: 0}}
 
 
 def _gpt_lanes():

@@ -177,6 +177,12 @@ def test_cached_port_tables_equal_fresh_ones(spec):
         assert layer.spine_ports(k) == t.spine_ports == tuple(sports)
         assert t.leaf_of == tuple(n for n, _ in lports)
         assert t.spine_of == tuple(m for m, _ in sports)
+        assert t.leaf_ports_of == tuple(
+            tuple(lp for lp, (n, _) in enumerate(lports) if n == leaf)
+            for leaf in range(spec.num_leafs))
+        assert t.spine_ports_of == tuple(
+            tuple(sp for sp, (m, _) in enumerate(sports) if m == spine)
+            for spine in range(spec.num_spines))
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
@@ -310,3 +316,332 @@ def test_budget_search_places_exactly_as_the_plain_count(monkeypatch, seed):
     assert report.jcts == report_plain.jcts
     assert report.jwts == report_plain.jwts
     assert report.n_finished == 300
+
+
+# ---------------------------------------------------------------------------
+# The indexed rewire planner ranks and moves exactly as the full port scan
+# ---------------------------------------------------------------------------
+
+class PlainPlanner:
+    """The rewire planner as it scanned every port of every OCS for each
+    lookup, rebuilding its cross-connect set and reverse maps each time,
+    and took every OCS's headroom twice per channel: the oracle."""
+
+    def __init__(self, state, budget=None):
+        self.state = state
+        self.spec = state.spec
+        self.ocs = state.ocs
+        self.circuits = [dict(c) for c in self.ocs.circuits]
+        spare = budget.spare if budget is not None else state.free_capacity()
+        self.spare = [row[:] for row in spare]
+        self.moves = []
+        self.unwired = []
+        ports = ocs_ports(self.spec)
+        self._lports = [p.leaf_ports for p in ports]
+        self._sports = [p.spine_ports for p in ports]
+
+    def _endpoints(self, k):
+        return self._lports[k], self._sports[k]
+
+    def _movable_leaf_port(self, k, leaf, avoid_spine=None):
+        lports, sports = self._endpoints(k)
+        taken = {(kk, pp) for (kk, pp, *_rest) in self.unwired}
+        taken |= set(self.state.xconn_owner)
+        for lp, (n, _) in enumerate(lports):
+            if n != leaf or (k, lp) in taken:
+                continue
+            sp = self.circuits[k].get(lp)
+            if sp is None:
+                return lp
+            m, _ = sports[sp]
+            if m == avoid_spine:
+                continue
+            if self.spare[n][m] > 0:
+                return lp
+        return None
+
+    def _free_spine_port(self, k, spine, for_leaf=None):
+        lports, sports = self._endpoints(k)
+        wired = {sp: lp for lp, sp in self.circuits[k].items()}
+        evictable = None
+        for sp, (m, _) in enumerate(sports):
+            if m != spine:
+                continue
+            if sp not in wired:
+                return sp
+            if evictable is None:
+                n2, _ = lports[wired[sp]]
+                if n2 != for_leaf and self.spare[n2][spine] > 0:
+                    evictable = sp
+        return evictable
+
+    def _headroom(self, k, leaf, spine):
+        lports, sports = self._endpoints(k)
+        taken = {(kk, pp) for (kk, pp, *_r) in self.unwired}
+        taken |= set(self.state.xconn_owner)
+        nl = 0
+        for lp, (n, _) in enumerate(lports):
+            if n != leaf or (k, lp) in taken:
+                continue
+            sp = self.circuits[k].get(lp)
+            if sp is None:
+                nl += 1
+                continue
+            m, _ = sports[sp]
+            if m != spine and self.spare[n][m] > 0:
+                nl += 1
+        if nl == 0:
+            return 0
+        wired = {s_: l_ for l_, s_ in self.circuits[k].items()}
+        ns = 0
+        for sp, (m, _) in enumerate(sports):
+            if m != spine:
+                continue
+            if sp not in wired:
+                ns += 2
+                continue
+            n2, _ = lports[wired[sp]]
+            if n2 != leaf and self.spare[n2][spine] > 0:
+                ns += 1
+        return min(nl, ns) if ns else 0
+
+    def add_channel(self, leaf, spine):
+        order = sorted(range(self.spec.num_ocs),
+                       key=lambda k: -self._headroom(k, leaf, spine))
+        for k in order:
+            if self._headroom(k, leaf, spine) <= 0:
+                break
+            lp = self._movable_leaf_port(k, leaf, avoid_spine=spine)
+            if lp is None:
+                continue
+            sp = self._free_spine_port(k, spine, for_leaf=leaf)
+            if sp is None:
+                continue
+            lports, sports = self._endpoints(k)
+            wired = {s_: l_ for l_, s_ in self.circuits[k].items()}
+            old_sp = self.circuits[k].pop(lp, None)
+            if old_sp is not None:
+                m_old, _ = sports[old_sp]
+                n, _ = lports[lp]
+                self.spare[n][m_old] -= 1
+            if sp in wired and wired[sp] != lp:
+                lp2 = wired[sp]
+                n2, _ = lports[lp2]
+                self.spare[n2][spine] -= 1
+                del self.circuits[k][lp2]
+                if old_sp is not None:
+                    m_old, _ = sports[old_sp]
+                    self.circuits[k][lp2] = old_sp
+                    self.spare[n2][m_old] += 1
+                    self.moves.append((k, lp2, old_sp))
+            self.circuits[k][lp] = sp
+            n, _ = lports[lp]
+            self.spare[n][spine] += 1
+            self.moves.append((k, lp, sp))
+            return True
+        return False
+
+    def ensure(self, need):
+        guard = 4 * self.spec.num_leafs * self.spec.uplinks_per_leaf
+        for (n, m), cnt in sorted(need.items()):
+            while self.spare[n][m] < cnt:
+                guard -= 1
+                if guard <= 0 or not self.add_channel(n, m):
+                    return False
+            self.spare[n][m] -= cnt
+        return True
+
+    def take_xconn(self, leaf_a, leaf_b, count):
+        done = 0
+        for k in range(self.spec.num_ocs):
+            while done < count:
+                pa = self._movable_leaf_port(k, leaf_a)
+                pb = self._movable_leaf_port(k, leaf_b)
+                if pa is None or pb is None:
+                    break
+                for p in (pa, pb):
+                    orig = self.circuits[k].get(p)
+                    self._unwire(k, p)
+                    self.unwired.append((k, p, -1 if orig is None else orig))
+                done += 1
+            if done >= count:
+                return True
+        return done >= count
+
+    def _unwire(self, k, lp):
+        sp = self.circuits[k].pop(lp, None)
+        if sp is not None:
+            lports, sports = self._endpoints(k)
+            n, _ = lports[lp]
+            m, _ = sports[sp]
+            self.spare[n][m] -= 1
+
+    def apply(self):
+        self.ocs.circuits = [dict(c) for c in self.circuits]
+
+
+def _planner_view(p):
+    return p.moves, p.circuits, p.spare, p.unwired
+
+
+def _rebuilt_index(p):
+    """The planner's reverse maps and cross-connect set, rebuilt afresh
+    from its circuits, its unwired ports and the fabric's patches."""
+    wired = [{sp: lp for lp, sp in c.items()} for c in p.circuits]
+    taken = {(k, lp) for k, lp, _ in p.unwired} | set(p.state.xconn_owner)
+    return wired, taken
+
+
+def _occupied_fabric(spec, rng, jobs):
+    """A fabric after a seeded run of OCS placements, commits and
+    releases, with extra reservations on random spare channels and a
+    cross-connect patch the full-scan planner lays between two leafs."""
+    st = FabricState(spec)
+    live = {}
+    sizes = [8, 16, 24, 32, 48, 64, 96, 128, 256]
+    for jid in range(jobs):
+        if live and rng.random() < 0.35:
+            victim = sorted(live)[int(rng.integers(len(live)))]
+            ocs_release(st, live.pop(victim))
+            continue
+        p = ocs_vclos_place(st, jid, int(rng.choice(sizes)))
+        if not isinstance(p, PlacementFailure):
+            commit(st, p)
+            live[jid] = p
+    spare = st.free_capacity()
+    links = {}
+    for _ in range(spec.num_leafs):
+        n = int(rng.integers(spec.num_leafs))
+        m = int(rng.integers(spec.num_spines))
+        if spare[n][m] > 0:
+            links[(n, m)] = int(rng.integers(1, spare[n][m] + 1))
+    if links:
+        st.reserve_links(10_000 + jobs, links)
+    a, b = sorted(rng.choice(spec.num_leafs, size=2, replace=False).tolist())
+    patch = PlainPlanner(st)
+    if patch.take_xconn(a, b, int(rng.integers(1, 5))):
+        patch.apply()
+        for k, lp, _ in patch.unwired:
+            st.xconn_owner[(k, lp)] = 20_000 + jobs
+    return st
+
+
+def _random_requests(spec, rng, count):
+    """Seeded planner calls: single channels, pinned demands and
+    two-leaf cross-connects."""
+    out = []
+    for _ in range(count):
+        r = rng.random()
+        n = int(rng.integers(spec.num_leafs))
+        m = int(rng.integers(spec.num_spines))
+        if r < 0.6:
+            out.append(("add_channel", (n, m)))
+        elif r < 0.85:
+            need = {(int(rng.integers(spec.num_leafs)),
+                     int(rng.integers(spec.num_spines))):
+                    int(rng.integers(1, 4)) for _ in range(3)}
+            out.append(("ensure", (need,)))
+        else:
+            b = (n + 1 + int(rng.integers(spec.num_leafs - 1))) \
+                % spec.num_leafs
+            out.append(("take_xconn", (min(n, b), max(n, b),
+                                       int(rng.integers(1, 9)))))
+    return out
+
+
+@pytest.mark.parametrize("spec,seed", [(CLUSTER512_OCS, 0),
+                                       (CLUSTER512_OCS, 1),
+                                       (CLUSTER512_OCS, 2),
+                                       (CLUSTER2048_OCS, 0),
+                                       (CLUSTER2048_OCS, 1)],
+                         ids=["512-0", "512-1", "512-2", "2048-0",
+                              "2048-1"])
+def test_planner_ranks_and_moves_as_the_plain_scan(spec, seed):
+    """On seeded occupancy, reservations and cross-connect patches, every
+    add_channel picks the OCS the full scan picks and leaves the same
+    moves, circuits and spare matrix; so do ensure and take_xconn."""
+    rng = np.random.default_rng(seed)
+    channels = picked = patched = 0
+    for jobs in (40, 80, 120):
+        st = _occupied_fabric(spec, rng, jobs)
+        patched += bool(st.xconn_owner)
+        fast, plain = RewirePlanner(st), PlainPlanner(st)
+        for op, args in _random_requests(spec, rng, 40):
+            n_moves = len(fast.moves)
+            got = getattr(fast, op)(*args)
+            assert got == getattr(plain, op)(*args), (op, args)
+            assert _planner_view(fast) == _planner_view(plain), (op, args)
+            if op == "add_channel":
+                channels += 1
+                picked += got
+                if got:   # the OCS the channel was built on
+                    assert fast.moves[-1][0] == plain.moves[-1][0]
+                    assert len(fast.moves) > n_moves
+    assert channels and picked and channels > picked
+    assert patched
+
+
+@pytest.mark.parametrize("spec", [CLUSTER512_OCS, CLUSTER2048_OCS],
+                         ids=["512", "2048"])
+def test_planner_index_equals_a_rebuild_after_every_move(spec, monkeypatch):
+    """After every add_channel of ensure and every take_xconn, the
+    planner's reverse maps and cross-connect set equal a fresh rebuild."""
+    checks = {"n": 0}
+    add = RewirePlanner.add_channel
+
+    def checked_add(self, leaf, spine):
+        got = add(self, leaf, spine)
+        assert (self._wired, self._taken) == _rebuilt_index(self)
+        checks["n"] += 1
+        return got
+
+    monkeypatch.setattr(RewirePlanner, "add_channel", checked_add)
+    rng = np.random.default_rng(7)
+    for jobs in (30, 90):
+        st = _occupied_fabric(spec, rng, jobs)
+        planner = RewirePlanner(st)
+        assert (planner._wired, planner._taken) == _rebuilt_index(planner)
+        for op, args in _random_requests(spec, rng, 30):
+            getattr(planner, op)(*args)
+            assert (planner._wired, planner._taken) == \
+                _rebuilt_index(planner)
+    assert checks["n"] > 0
+
+
+def _ocs_steps(monkeypatch, seed, jobs=400):
+    """Every placement of one seeded ocs-vclos run on CLUSTER512_OCS, with
+    the OCS circuits as each placement left them, and the report."""
+    placed = []
+    strat = type(get_strategy("ocs-vclos"))
+    orig = strat.place
+
+    def place(self, ctx, job_id, num_gpus, job=None):
+        out = orig(self, ctx, job_id, num_gpus, job)
+        circuits = [sorted(c.items()) for c in ctx.state.ocs.circuits]
+        if isinstance(out, PlacementFailure):
+            placed.append((job_id, out.reason, circuits))
+        else:
+            links = out.vclos.links if out.vclos is not None else {}
+            placed.append((job_id, list(out.gpus), dict(links),
+                           list(out.xconn_ports), circuits))
+        return out
+
+    monkeypatch.setattr(strat, "place", place)
+    trace = generate_trace(WorkloadSpec(num_jobs=jobs, mean_interarrival=80.0,
+                                        max_gpus=256, seed=seed))
+    report = simulate(CLUSTER512_OCS, trace, "ocs-vclos")
+    monkeypatch.setattr(strat, "place", orig)
+    return placed, report
+
+
+def test_indexed_planner_places_a_trace_exactly_as_the_plain_scan(
+        monkeypatch):
+    placed, report = _ocs_steps(monkeypatch, seed=2)
+    monkeypatch.setattr(ocs, "RewirePlanner", PlainPlanner)
+    placed_plain, report_plain = _ocs_steps(monkeypatch, seed=2)
+    kinds = {len(step) for step in placed}
+    assert kinds == {3, 5}          # failures and placements both
+    assert any(step[3] for step in placed if len(step) == 5)   # xconn
+    assert placed == placed_plain
+    assert report.jcts == report_plain.jcts
+    assert report.n_finished == 400
