@@ -14,7 +14,7 @@ Layers:
   jobs       — DML workload profiles + dataset generators
   workloads  — reproducible Poisson/CSV arrival traces for campaigns
   traces     — real-trace ingestion: adapters, streaming reader, windows
-  simulator  — event-driven flow-level cluster simulator (incremental rates)
+  simulator  — event-driven flow-level simulator (v1 reference, v2 engine)
   runtime    — fault-tolerant cell execution: retries, timeouts, journal
   campaign   — strategy × policy × load × seed sweep driver + aggregation
   figures    — paper-figure experiment specs (smoke/paper scales, tabular)

@@ -26,8 +26,9 @@ so the rank-level (src, dst, phase) arrays, phase byte counts and both
 contention-free iteration times are computed once per (model, batch, size,
 algo) and shared by every lane of the trace.
 
-**Oracle contract** (docs/batched.md): the sequential v1/v2 engines remain
-the ground truth.  This engine replicates their arithmetic operation-for
+**Oracle contract** (docs/batched.md): the serial engines remain the
+ground truth — v1 the plain full-recompute reference, v2 the incremental
+heap engine.  This engine replicates their arithmetic operation-for
 -operation (same left-to-right accumulations, same guards), so qualifying
 runs are *bit-exact* — asserted per strategy by ``tests/test_batched.py``
 and as a hypothesis property in ``tests/test_properties.py``.  A cell

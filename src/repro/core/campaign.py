@@ -319,7 +319,6 @@ def _run_cell(spec: ClusterSpec, trace: List[Job], config: SimConfig,
 def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
                  workload: Optional[WorkloadSpec] = None,
                  trace: Optional[Sequence[Job]] = None,
-                 incremental: Optional[bool] = None,
                  engine: Optional[str] = None,
                  workers: Optional[int] = None,
                  store: Optional[str] = None,
@@ -342,10 +341,10 @@ def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
     (the trace fixes the arrival process) and seeds only vary the
     simulator's internal randomness (ECMP hashing, relaxed placement).
 
-    ``engine`` — simulator engine per cell (``"v2"`` heap engine default,
-    ``"v1"`` scan engine, ``"batched"`` lane engine — serial campaigns
-    run all qualifying cells in lockstep, see docs/batched.md); all
-    produce bit-identical schedules.
+    ``engine`` — simulator engine per cell: ``"v2"`` the serial engine
+    (default), ``"v1"`` the plain reference, ``"batched"`` the lane
+    engine (serial campaigns run all qualifying cells in lockstep, see
+    docs/batched.md); all produce bit-identical schedules.
 
     ``workers`` — when > 1, shard grid cells across a
     ``ProcessPoolExecutor``.  Results are merged in grid order regardless
@@ -363,7 +362,7 @@ def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
     circuits to rewire).
 
     ``config`` — a :class:`repro.core.config.SimConfig` carrying the
-    engine/incremental/workers/store/ilp_time_limit knobs in one object
+    engine/workers/store/ilp_time_limit knobs in one object
     (its per-cell fields — strategy, scheduler, seed — are overridden by
     the grid).  Loose kwargs explicitly passed alongside it override the
     matching config fields; omitted ones keep the config's values.
@@ -385,8 +384,8 @@ def run_campaign(spec: ClusterSpec, grid: CampaignGrid,
     """
     with obs.span("campaign.run"):
         config = (config or SimConfig()).with_overrides(
-            incremental=incremental, engine=engine, workers=workers,
-            store=store, ilp_time_limit=ilp_time_limit,
+            engine=engine, workers=workers, store=store,
+            ilp_time_limit=ilp_time_limit,
             cell_timeout=cell_timeout, max_retries=max_retries,
             quarantine=quarantine)
         return _run_campaign(spec, grid, workload, trace, ocs_spec, config,

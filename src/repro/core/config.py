@@ -23,10 +23,10 @@ from .events import ClusterEvent
 from .scheduler import QUEUE_POLICIES
 from .strategies import Strategy, get_strategy
 
-#: simulator engines — ``v1`` scan engine, ``v2`` heap engine (default),
-#: ``batched`` lane engine (flat-array lockstep runner, falls back to v2
-#: for non-qualifying configs); bit-identical schedules (see
-#: docs/simulator.md and docs/batched.md)
+#: simulator engines — ``v1`` the plain full-recompute reference, ``v2``
+#: the serial heap engine (default), ``batched`` the lane engine (many
+#: cells in lockstep, falls back to v2 for non-qualifying configs);
+#: bit-identical schedules (see docs/simulator.md and docs/batched.md)
 ENGINES = ("v1", "v2", "batched")
 #: campaign per-cell sample stores — keep everything vs condense to
 #: bounded-size order statistics
@@ -47,7 +47,6 @@ class SimConfig:
     scheduler: str = "fifo"
     seed: int = 0
     ilp_time_limit: float = 2.0
-    incremental: bool = True
     engine: str = "v2"
     max_time: float = math.inf
     # dynamic-events knobs (repro.core.events): the event trace applied to

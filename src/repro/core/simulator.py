@@ -5,21 +5,27 @@ A fluid-rate model: each running job progresses at
 ideal-iteration; rates change only when the running set changes (arrival
 placement or completion), so the simulation advances event-to-event.
 
-Two engines share one numerical contract (see docs/simulator.md):
+Two serial engines share one numerical contract (see docs/simulator.md):
 
-  * ``engine="v1"`` — the scan engine: per-event minimum over the running
-    set, Counter-backed link loads, per-job rate re-solve in Python.  The
-    ``incremental`` flag selects dirty-link-scoped re-solving (default) or
-    the faithful full-recompute sweep; both are bit-identical.
-  * ``engine="v2"`` — the discrete-event engine (default): a lazy-deletion
-    binary heap of completion events keyed ``(finish_time, placement_order)``
-    replaces the min-over-running-jobs scan, link load and per-phase flow
-    counts live in flat numpy arrays over interned link ids
-    (:class:`repro.core.routing.LinkSpace`), rate resolution is batched
-    across the affected jobs through
+  * ``engine="v1"`` — the plain reference: per-event minimum over the
+    running set, Counter-backed link loads, and every running job's rate
+    re-solved in Python after each running-set change (full recompute).
+  * ``engine="v2"`` — the serial discrete-event engine (default): a
+    lazy-deletion binary heap of completion events keyed
+    ``(finish_time, placement_order)`` replaces the min-over-running-jobs
+    scan, link load and per-phase flow counts live in flat numpy arrays
+    over interned link ids (:class:`repro.core.routing.LinkSpace`), only
+    jobs crossing a link whose load changed are re-solved, batched through
     :func:`repro.core.fairshare.phase_worst_loads` (numpy↔JAX dispatched),
     and failed placements are memoised against a fabric-state version so a
     blocked queue head costs O(1) per event instead of a placement attempt.
+    Its event-loop step is public — :meth:`ClusterSimulator.next_finish`,
+    :meth:`~ClusterSimulator.finish_next`, :meth:`~ClusterSimulator.arrive`
+    — and the scheduler daemon (:mod:`repro.service`) steps the same
+    methods ``_run_v2`` does.
+
+``engine="batched"`` (:mod:`repro.core.batched`) is the lane-batched fast
+path over many cells at once; what it does not qualify for runs on v2.
 
 Both engines settle a job's remaining work *only when its rate value
 changes* (work = elapsed × rate over the constant-rate segment), which makes
@@ -33,8 +39,8 @@ the same loops: job preemption with checkpoint-restart cost, server/link
 failure + recovery, elastic GPU resize (``SimConfig.events``), and a
 periodic migration-defragmentation pass (``SimConfig.defrag_interval``;
 strategies opt in via ``Strategy.supports_migration``).  Every handler is
-engine-agnostic — it mutates engine state only through a per-run dispatch
-tuple — so the bit-parity contract extends to arbitrary churn
+engine-agnostic — it mutates engine state only through a per-engine
+dispatch tuple — so the bit-parity contract extends to arbitrary churn
 (``tests/test_events.py``, hypothesis suite in ``tests/test_properties.py``).
 
 Strategies are **plugins**: every per-strategy decision (routing factory,
@@ -254,7 +260,8 @@ def _finish_time(rj, now: float) -> float:
 # ---------------------------------------------------------------------------
 
 class ClusterSimulator:
-    """The engine pair behind :func:`simulate`.
+    """The serial engines behind :func:`simulate`: v1, the full-recompute
+    reference, and v2, the incremental heap engine.
 
     Configuration arrives either as legacy loose kwargs or as one
     :class:`repro.core.config.SimConfig` (``config=``; loose kwargs
@@ -266,12 +273,16 @@ class ClusterSimulator:
     ``Strategy.place`` (``spec`` / ``state`` / ``seed`` /
     ``ilp_time_limit`` plus the :meth:`dense_link_load` /
     :meth:`leaf_link_load` traffic views).
+
+    Besides :meth:`run`, v2 can be stepped from outside, one event at a
+    time: :meth:`next_finish` / :meth:`finish_next` (completions),
+    :meth:`finish_until` (completions up to a time), :meth:`arrive`,
+    :meth:`apply_event` and the side-effect-free :meth:`try_place`.
     """
 
     def __init__(self, spec: ClusterSpec, strategy=None,
                  scheduler: Optional[str] = None, seed: Optional[int] = None,
                  ilp_time_limit: Optional[float] = None,
-                 incremental: Optional[bool] = None,
                  engine: Optional[str] = None,
                  config: Optional[SimConfig] = None):
         # one precedence rule, shared with simulate(): every loose kwarg
@@ -283,7 +294,7 @@ class ClusterSimulator:
         config = config.with_overrides(strategy=strategy, scheduler=scheduler,
                                        seed=seed,
                                        ilp_time_limit=ilp_time_limit,
-                                       incremental=incremental, engine=engine)
+                                       engine=engine)
         strat = config.resolve_strategy()
         if config.scheduler not in strat.queue_policies:
             raise ValueError(
@@ -301,7 +312,6 @@ class ClusterSimulator:
         self.scheduler = config.scheduler
         self.seed = config.seed
         self.ilp_time_limit = config.ilp_time_limit
-        self.incremental = config.incremental
         self.engine = config.engine
         self.state = FabricState(spec)
         self.routing = strat.make_routing(spec, self.seed)
@@ -310,12 +320,8 @@ class ClusterSimulator:
         self.frag_reason: Dict[int, str] = {}   # job_id -> first blocking cause
         self.slowdowns: Dict[int, float] = {}   # job_id -> JRT / ideal JRT
         self.now = 0.0
-        # v1 incremental-rate machinery: maintained global link load,
-        # link → jobs index, dirty links/jobs since the last resolution
+        # v1 global link load, kept by add/remove
         self._link_load: Counter = Counter()
-        self._link_users: Dict[object, Set[int]] = {}
-        self._dirty_links: Set[object] = set()
-        self._dirty_jobs: Set[int] = set()
         # v2 array state: dense link ids, flat load vector, dirty-link list,
         # and a link → running-job bitset index — users[l] is a row of
         # uint64 words whose set bits are the slots of jobs crossing link l,
@@ -360,10 +366,20 @@ class ClusterSimulator:
         self.n_resizes = 0
         self.n_migrations = 0
         self.migration_bytes = 0.0
-        self._ops: Optional[tuple] = None   # set per run(): engine dispatch
+        # engine dispatch for the shared event handlers: remove / add /
+        # try-schedule / recompute rates (batched falls back to v2)
+        if self.engine == "v1":
+            self._ops = (self._remove_running, self._add_running,
+                         self._try_schedule, self._recompute_rates)
+        else:
+            self._ops = (self._remove_running_v2, self._add_running_v2,
+                         self._try_schedule_v2, self._recompute_rates_v2)
 
     # -- strategy plumbing: one registry dispatch, no per-strategy branches --
-    def _place(self, job: Job):
+    def try_place(self, job: Job):
+        """Where ``job`` would go now: a Placement or a PlacementFailure.
+        Commits nothing (the failed-placement memo relies on placement
+        being a pure function of fabric state)."""
         check_layout(self.strategy_obj, self.spec, job)
         # O(1) fast-fail: fewer free GPUs than requested can only ever yield
         # PlacementFailure("gpu") (every strategy needs num_gpus GPUs), so
@@ -557,7 +573,7 @@ class ClusterSimulator:
         return min(spec.scale_of_server(spec.server_of_gpu(g))
                    for g in gpus)
 
-    # -- running-set mutation (keeps the link index consistent) -------------
+    # -- running-set mutation (keeps the global link load consistent) -------
     def _add_running(self, job: Job, placement: Placement) -> None:
         rj = self._build_running(job, placement)
         if obs.recording() and not rj.intra_server:
@@ -568,12 +584,6 @@ class ClusterSimulator:
         self.running[job.job_id] = rj
         for l, c in rj.union_links.items():
             self._link_load[l] += c
-            self._link_users.setdefault(l, set()).add(job.job_id)
-        if rj.union_links:
-            self._dirty_links.update(rj.union_links)
-            self._dirty_jobs.add(job.job_id)
-        # a job with no fabric links keeps its default rate of 1.0 forever
-        # (NVLink-local or reserved), so it never needs a rate re-solve
 
     def _remove_running(self, jid: int) -> _RunningJob:
         rj = self.running.pop(jid)
@@ -581,13 +591,6 @@ class ClusterSimulator:
             self._link_load[l] -= c
             if self._link_load[l] <= 0:
                 del self._link_load[l]
-            users = self._link_users.get(l)
-            if users is not None:
-                users.discard(jid)
-                if not users:
-                    del self._link_users[l]
-        self._dirty_links.update(rj.union_links)
-        self._dirty_jobs.discard(jid)
         return rj
 
     def _job_rate(self, rj: _RunningJob) -> float:
@@ -630,38 +633,13 @@ class ClusterSimulator:
             rj.t_fin = _finish_time(rj, self.now)
 
     def _recompute_rates(self) -> None:
-        """Resolve progress rates after a running-set change.
-
-        Incremental mode touches newly placed jobs plus every job sharing a
-        dirty link; a job whose links all kept their load cannot change rate,
-        so skipping it is exact, not approximate.
-        """
+        """Re-solve every running job's rate after a running-set change
+        (the full-recompute reference; v2 re-solves only the jobs whose
+        links changed load, and must match this bit for bit)."""
         if self.isolated:
-            # reservations guarantee share = 1 (the _RunningJob default)
-            self._dirty_links.clear()
-            self._dirty_jobs.clear()
-            return
-        if self.incremental:
-            affected = set(self._dirty_jobs)
-            for l in self._dirty_links:
-                affected.update(self._link_users.get(l, ()))
-            for jid in affected:
-                rj = self.running.get(jid)
-                if rj is not None:
-                    self._apply_rate(rj, self._job_rate(rj))
-        else:
-            # faithful full-recompute baseline (the seed algorithm): rebuild
-            # the global load from scratch, re-solve every running job.  The
-            # rebuild equals the maintained counter (integer arithmetic), so
-            # both modes produce bit-identical schedules.
-            load: Counter = Counter()
-            for rj in self.running.values():
-                load.update(rj.union_links)
-            self._link_load = load
-            for rj in self.running.values():
-                self._apply_rate(rj, self._job_rate(rj))
-        self._dirty_links.clear()
-        self._dirty_jobs.clear()
+            return   # reservations guarantee share = 1 (the default rate)
+        for rj in self.running.values():
+            self._apply_rate(rj, self._job_rate(rj))
         # ocs-relax keeps locality penalty implicit: scattered placement
         # yields many cross-leaf flows, captured by the shares above.
 
@@ -669,7 +647,7 @@ class ClusterSimulator:
     def _try_schedule(self) -> bool:
         changed = False
         for job in order_queue(self.queue, self.scheduler):
-            res = self._place(job)
+            res = self.try_place(job)
             if isinstance(res, PlacementFailure):
                 self.frag_reason.setdefault(job.job_id, res.reason)
                 if self.scheduler == "fifo":
@@ -717,7 +695,7 @@ class ClusterSimulator:
             elif next_event <= min(next_arrival, next_defrag):
                 ev = events[ei]
                 ei += 1
-                self._handle_event(ev)
+                self.apply_event(ev)
             elif next_defrag <= next_arrival:
                 self._next_defrag += self._defrag_interval
                 self._defrag_pass()
@@ -742,9 +720,9 @@ class ClusterSimulator:
     # =======================================================================
     # dynamic events — ONE implementation for both engines.  Every handler
     # mutates engine state only through the _ops dispatch tuple (remove /
-    # add / try-schedule / recompute-rates bound per run()), so the exact
-    # same settle/release/requeue sequence happens under v1 and v2 — the
-    # events extension of the bit-parity contract.
+    # add / try-schedule / recompute-rates, bound per engine in __init__),
+    # so the exact same settle/release/requeue sequence happens under v1
+    # and v2 — the events extension of the bit-parity contract.
     # =======================================================================
 
     def _preempt_running(self, jid: int, penalty: float) -> None:
@@ -799,9 +777,8 @@ class ClusterSimulator:
 
     def _link_flow_users(self, n: int, m: int) -> Set[int]:
         """Running jobs with live flows on any channel of fabric link
-        (leaf n, spine m) — computed from each engine's maintained
-        link→jobs index (identical contents by the parity contract)."""
-        out: Set[int] = set()
+        (leaf n, spine m): v2 reads its link→jobs bitset index, v1 scans
+        the running set (the same set by the parity contract)."""
         channels = self._ls.channels
         if self.engine != "v1":
             ids = [self._ls.id_of(("up", n, m, c)) for c in range(channels)]
@@ -809,13 +786,12 @@ class ClusterSimulator:
                     for c in range(channels)]
             words = np.bitwise_or.reduce(self._users[np.asarray(ids)], axis=0)
             bits = np.unpackbits(words.view(np.uint8), bitorder="little")
-            for s in np.flatnonzero(bits):
-                out.add(self._slot_map[s].job.job_id)
-            return out
-        for c in range(channels):
-            out.update(self._link_users.get(("up", n, m, c), ()))
-            out.update(self._link_users.get(("down", m, n, c), ()))
-        return out
+            return {self._slot_map[s].job.job_id for s in np.flatnonzero(bits)}
+        links = {(kind, a, b, c) for kind, a, b in (("up", n, m),
+                                                    ("down", m, n))
+                 for c in range(channels)}
+        return {jid for jid, rj in self.running.items()
+                if not links.isdisjoint(rj.union_links)}
 
     def _ev_link_fail(self, ev: ClusterEvent):
         n, m = ev.leaf, ev.spine
@@ -875,7 +851,10 @@ class ClusterSimulator:
                        "link-recover": _ev_link_recover,
                        "resize": _ev_resize}
 
-    def _handle_event(self, ev: ClusterEvent) -> None:
+    def apply_event(self, ev: ClusterEvent) -> None:
+        """Apply one churn event at ``now`` (either engine): log it, sample
+        the fragmentation index, and re-schedule + re-solve if it changed
+        anything."""
         changed, a, b, n_affected = self._EVENT_HANDLERS[ev.kind](self, ev)
         self.event_log.append((self.now, ev.kind, a, b, n_affected))
         self.frag_series.append([self.now, frag_index(self.state)])
@@ -923,7 +902,7 @@ class ClusterSimulator:
                 if key[0] == 1 and key[1] <= best_servers:
                     continue    # already maximally local
                 release(self.state, jid, p)
-                res = self._place(rj.job)
+                res = self.try_place(rj.job)
                 if isinstance(res, PlacementFailure) or \
                         self._locality_key(spec, res.gpus) >= key:
                     commit(self.state, p)   # restore; rj never touched
@@ -1130,15 +1109,13 @@ class ClusterSimulator:
         dirty = (self._dirty_cols[0] if len(self._dirty_cols) == 1
                  else np.concatenate(self._dirty_cols))
         self._dirty_cols.clear()
-        if self.incremental:
-            # one OR-reduce over the dirty links' user bitsets gives every
-            # affected job's slot (x86/arm little-endian word layout)
-            words = np.bitwise_or.reduce(self._users[dirty], axis=0)
-            bits = np.unpackbits(words.view(np.uint8), bitorder="little")
-            affected = [self._slot_map[s] for s in np.flatnonzero(bits)]
-        else:
-            affected = [rj for rj in self.running.values()
-                        if rj.uidx is not None]
+        # one OR-reduce over the dirty links' user bitsets gives every
+        # affected job's slot (x86/arm little-endian word layout); a job
+        # whose links all kept their load cannot change rate, so skipping
+        # it is exact
+        words = np.bitwise_or.reduce(self._users[dirty], axis=0)
+        bits = np.unpackbits(words.view(np.uint8), bitorder="little")
+        affected = [self._slot_map[s] for s in np.flatnonzero(bits)]
         if not affected:
             return
         # batched contended-subgraph solve: one gather + segmented max over
@@ -1205,7 +1182,7 @@ class ClusterSimulator:
                 if self.scheduler == "fifo":
                     break
                 continue
-            res = self._place(job)
+            res = self.try_place(job)
             if isinstance(res, PlacementFailure):
                 self.frag_reason.setdefault(job.job_id, res.reason)
                 self._fail_version[job.job_id] = ver
@@ -1221,11 +1198,57 @@ class ClusterSimulator:
             changed = True
         return changed
 
+    # -- v2 event-loop step: the one body _run_v2 and the daemon share -----
+    def next_finish(self) -> float:
+        """Time of the next completion (``inf`` when nothing runs).  Drops
+        stale heap entries on the way (lazy deletion): the job finished,
+        or its rate changed since the push (version mismatch; restarts keep
+        version numbers monotone per job via _ver_base)."""
+        heap, running = self._heap, self.running
+        while heap:
+            _, _, jid, ver = heap[0]
+            rj = running.get(jid)
+            if rj is not None and rj.version == ver:
+                return heap[0][0]
+            heapq.heappop(heap)
+        return math.inf
+
+    def finish_next(self) -> Tuple[int, float]:
+        """Complete the job at the top of the heap (call after
+        :meth:`next_finish`): set the clock to its finish time, free its
+        resources, re-schedule and re-solve.  Returns ``(job_id, t_fin)``."""
+        t_fin, _, fin_id, _ = heapq.heappop(self._heap)
+        self.now = t_fin
+        rj = self._remove_running_v2(fin_id)
+        self._finish_job(rj, fin_id)
+        self._state_version += 1
+        self._try_schedule_v2()
+        self._recompute_rates_v2()
+        return fin_id, t_fin
+
+    def finish_until(self, t: float) -> List[Tuple[int, float]]:
+        """Run every completion with finish time ``<= t``, in order, then
+        move the clock to ``t`` when it is finite.  Completions at ``t``
+        come first, as in the run loop's tie order."""
+        done = []
+        while (t_fin := self.next_finish()) <= t and t_fin < math.inf:
+            done.append(self.finish_next())
+        if math.isfinite(t) and t > self.now:
+            self.now = t
+        return done
+
+    def arrive(self, job: Job) -> None:
+        """Queue ``job`` at ``now`` (registering it for resize events),
+        try to schedule, and re-solve if anything placed."""
+        self._jobs_by_id.setdefault(job.job_id, job)
+        self.queue.append(job)
+        if self._try_schedule_v2():
+            self._recompute_rates_v2()
+
     def _run_v2(self, arrivals: List[Job], max_time: float) -> None:
         ai = 0
         ei = 0
         events = self._events
-        heap = self._heap
         running = self.running
         while (ai < len(arrivals) or self.queue or running) \
                 and self.now < max_time:
@@ -1236,43 +1259,24 @@ class ClusterSimulator:
             next_defrag = (self._next_defrag
                            if (running or ei < len(events)
                                or ai < len(arrivals)) else math.inf)
-            # lazy deletion: drop heap entries whose job finished or whose
-            # rate changed since the push (version mismatch; restarts keep
-            # version numbers monotone per job via _ver_base)
-            while heap:
-                t, order, jid, ver = heap[0]
-                rj = running.get(jid)
-                if rj is None or rj.version != ver:
-                    heapq.heappop(heap)
-                    continue
-                break
-            next_finish = heap[0][0] if heap else math.inf
+            next_finish = self.next_finish()
             t_next = min(next_arrival, next_finish, next_event, next_defrag)
             if math.isinf(t_next):
                 break
             self.now = t_next
             # tie order (shared with v1): finish, event, defrag, arrival
-            if heap and \
-                    next_finish <= min(next_arrival, next_event, next_defrag):
-                _, _, fin_id, _ = heapq.heappop(heap)
-                rj = self._remove_running_v2(fin_id)
-                self._finish_job(rj, fin_id)
-                self._state_version += 1
-                self._try_schedule_v2()
-                self._recompute_rates_v2()
+            if next_finish <= min(next_arrival, next_event, next_defrag):
+                self.finish_next()
             elif next_event <= min(next_arrival, next_defrag):
                 ev = events[ei]
                 ei += 1
-                self._handle_event(ev)
+                self.apply_event(ev)
             elif next_defrag <= next_arrival:
                 self._next_defrag += self._defrag_interval
                 self._defrag_pass()
             else:
-                job = arrivals[ai]
+                self.arrive(arrivals[ai])
                 ai += 1
-                self.queue.append(job)
-                if self._try_schedule_v2():
-                    self._recompute_rates_v2()
 
     # -- entry point ---------------------------------------------------------
     def run(self, jobs: Sequence[Job],
@@ -1293,12 +1297,8 @@ class ClusterSimulator:
             if rep is not None:
                 return rep
         if self.engine == "v1":
-            self._ops = (self._remove_running, self._add_running,
-                         self._try_schedule, self._recompute_rates)
             self._run_v1(list(jobs), max_time)
         else:
-            self._ops = (self._remove_running_v2, self._add_running_v2,
-                         self._try_schedule_v2, self._recompute_rates_v2)
             self._run_v2(list(jobs), max_time)
         return self.build_report(jobs)
 
@@ -1328,7 +1328,6 @@ class ClusterSimulator:
 def simulate(spec: ClusterSpec, jobs: Sequence[Job], strategy=None,
              scheduler: Optional[str] = None, seed: Optional[int] = None,
              ilp_time_limit: Optional[float] = None,
-             incremental: Optional[bool] = None,
              engine: Optional[str] = None,
              config: Optional[SimConfig] = None) -> MetricsReport:
     """Run one trace under one strategy and return its metrics.
@@ -1348,8 +1347,7 @@ def simulate(spec: ClusterSpec, jobs: Sequence[Job], strategy=None,
                          "or a SimConfig")
     config = (config or SimConfig()).with_overrides(
         strategy=strategy, scheduler=scheduler, seed=seed,
-        ilp_time_limit=ilp_time_limit, incremental=incremental,
-        engine=engine)
+        ilp_time_limit=ilp_time_limit, engine=engine)
     sim = ClusterSimulator(spec, config=config)
     # copy jobs so runs under different strategies don't contaminate each other
     import copy

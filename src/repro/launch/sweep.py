@@ -185,13 +185,11 @@ def campaign_main(argv) -> None:
     ap.add_argument("--max-windows", type=int, default=None, metavar="N",
                     help="stop after N windows — the streaming reader "
                          "never scans past the windowed span")
-    ap.add_argument("--full-recompute", action="store_true",
-                    help="use the full-recompute rate engine (debug)")
     ap.add_argument("--engine", default="v2", choices=ENGINES,
                     help="simulator engine: v2 heap engine (default), the "
-                         "v1 scan engine, or the batched lane engine "
-                         "(serial campaigns advance qualifying cells in "
-                         "lockstep; docs/batched.md) — bit-identical "
+                         "v1 full-recompute reference, or the batched lane "
+                         "engine (serial campaigns advance qualifying cells "
+                         "in lockstep; docs/batched.md) — bit-identical "
                          "schedules")
     ap.add_argument("--workers", type=int, default=None,
                     help="shard grid cells across N processes "
@@ -369,7 +367,6 @@ def campaign_main(argv) -> None:
         else None, **churn)
     config = SimConfig(engine=args.engine,
                        trace_format=args.trace_format,
-                       incremental=not args.full_recompute,
                        workers=args.workers,
                        store="stream" if args.stream else "full",
                        defrag_interval=args.defrag,

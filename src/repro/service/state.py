@@ -6,13 +6,13 @@ scheduler *service* needs the same engine driven incrementally: jobs are
 submitted one at a time, churn events arrive out of band, and the daemon
 must survive a crash.  :class:`LiveCluster` is that incremental driver:
 
-* it hosts one v2 :class:`ClusterSimulator` and steps it with the **exact**
-  event-loop semantics of ``_run_v2`` (lazy-deletion completion heap,
-  finish → event → arrival tie order, state-version bumps, try-schedule +
-  recompute after every mutation) — so a recorded trace fed through
-  :func:`replay_trace` yields placements and completion times bit-identical
-  to offline ``simulate()`` on the same trace (the differential replay
-  oracle, ``tests/test_service.py``),
+* it hosts one v2 :class:`ClusterSimulator` and steps it through the
+  engine's public step — ``finish_until`` (a loop over ``next_finish`` /
+  ``finish_next``), ``arrive`` and ``apply_event``, the methods
+  ``_run_v2`` itself calls, in its finish → event → arrival tie order —
+  so a recorded trace fed through :func:`replay_trace` yields placements
+  and completion times bit-identical to offline ``simulate()`` on the
+  same trace (the differential replay oracle, ``tests/test_service.py``),
 * every ingested mutation (submit / churn event / clock advance) is
   appended to a durable :class:`ServiceLog` — the
   :class:`~repro.core.runtime.LineJournal` line-atomic format with
@@ -36,7 +36,6 @@ the *scheduler* service.  It is unrelated to ``repro.serve`` /
 
 from __future__ import annotations
 
-import heapq
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -51,8 +50,7 @@ from ..core.strategies import check_layout
 from ..core.topology import ClusterSpec
 
 __all__ = ["LiveCluster", "ServiceLog", "RecordingSimulator",
-            "drain_completions", "replay_trace", "service_schema",
-            "job_to_json", "job_from_json"]
+           "replay_trace", "service_schema", "job_to_json", "job_from_json"]
 
 #: job ids at or above this are what-if probes (never logged or persisted)
 PROBE_ID_BASE = 2_000_000_000
@@ -139,40 +137,6 @@ class RecordingSimulator(ClusterSimulator):
                                 tuple(placement.gpus)))
 
 
-def drain_completions(sim: ClusterSimulator, t: float,
-                      ) -> List[Tuple[int, float]]:
-    """Process every completion with ``t_fin <= t``, replicating the v2
-    run loop exactly: lazy-deletion heap scrub, clock set to each finish
-    time, state-version bump, try-schedule, recompute.  Returns the
-    ``(job_id, finish_time)`` list in completion order.  Finally moves the
-    clock to ``t`` (when finite) — completions tie *before* any same-time
-    event or arrival, matching ``_run_v2``'s ``next_finish <= min(...)``."""
-    heap = sim._heap
-    running = sim.running
-    done: List[Tuple[int, float]] = []
-    while True:
-        while heap:
-            _tf, _order, jid, ver = heap[0]
-            rj = running.get(jid)
-            if rj is None or rj.version != ver:
-                heapq.heappop(heap)
-                continue
-            break
-        if not heap or heap[0][0] > t:
-            break
-        tf, _, fin_id, _ = heapq.heappop(heap)
-        sim.now = tf
-        rj = sim._remove_running_v2(fin_id)
-        sim._finish_job(rj, fin_id)
-        sim._state_version += 1
-        sim._try_schedule_v2()
-        sim._recompute_rates_v2()
-        done.append((fin_id, tf))
-    if math.isfinite(t) and t > sim.now:
-        sim.now = t
-    return done
-
-
 # ---------------------------------------------------------------------------
 # LiveCluster
 # ---------------------------------------------------------------------------
@@ -184,7 +148,7 @@ class LiveCluster:
     ----------
     spec, config:
         Cluster shape and scheduling configuration.  The engine is always
-        ``v2`` (the incremental stepping below *is* the v2 loop); churn
+        ``v2`` (the daemon steps the v2 loop's own methods); churn
         must arrive through :meth:`ingest`, not ``config.events``; defrag
         ticks need the offline loop's clock and are rejected.
     log:
@@ -210,12 +174,6 @@ class LiveCluster:
         self.config = config
         self.quotas: Dict[str, int] = dict(quotas or {})
         self.sim = RecordingSimulator(spec, config=config)
-        # the engine-dispatch tuple run() would normally bind — the event
-        # handlers (_handle_event -> _ops[2]/_ops[3]) go through it
-        self.sim._ops = (self.sim._remove_running_v2,
-                         self.sim._add_running_v2,
-                         self.sim._try_schedule_v2,
-                         self.sim._recompute_rates_v2)
         self.jobs: List[Job] = []                 # admitted, arrival order
         self.tenants: Dict[int, str] = {}         # job_id -> tenant
         self.completions: List[Tuple[int, float]] = []
@@ -282,7 +240,7 @@ class LiveCluster:
 
     def _drain(self, t: float) -> List[Tuple[int, float]]:
         before = self.sim.now
-        done = drain_completions(self.sim, t)
+        done = self.sim.finish_until(t)
         self.completions.extend(done)
         # completions mutate placement state; pure clock movement shifts
         # every what-if prediction's absolute times — both invalidate
@@ -354,7 +312,7 @@ class LiveCluster:
         if job.job_id >= PROBE_ID_BASE:
             raise ValueError(f"job ids >= {PROBE_ID_BASE} are reserved "
                              f"for what-if probes")
-        if job.job_id in self.sim._jobs_by_id:
+        if job.job_id in self.tenants:
             raise ValueError(f"duplicate job_id {job.job_id}")
         self._check_monotonic(job.arrival, f"submit of job {job.job_id}")
         if _log and self._log is not None:
@@ -371,10 +329,7 @@ class LiveCluster:
         sim = self.sim
         self.jobs.append(job)
         self.tenants[job.job_id] = tenant
-        sim._jobs_by_id[job.job_id] = job
-        sim.queue.append(job)
-        if sim._try_schedule_v2():
-            sim._recompute_rates_v2()
+        sim.arrive(job)
         self.version += 1
         placed = job.job_id in sim.running
         out = {"job_id": job.job_id, "admitted": True, "placed": placed,
@@ -395,9 +350,9 @@ class LiveCluster:
             self._log.append_record({"kind": "event", "ev": ev.to_json()})
         self.ingested += 1
         self._drain(ev.time)
-        self.sim._handle_event(ev)
+        self.sim.apply_event(ev)
         self.version += 1
-        # _handle_event always logs (now, kind, a, b, n_affected)
+        # apply_event always logs (now, kind, a, b, n_affected)
         return {"kind": ev.kind, "t": self.sim.now,
                 "n_affected": self.sim.event_log[-1][4]}
 
@@ -425,7 +380,7 @@ class LiveCluster:
         memoisation depends on that), and nothing is committed.  Bounded
         latency: O(1) fast-fail when free GPUs < request, and MILP
         fallbacks are wall-clock-capped by ``config.ilp_time_limit``."""
-        res = self.sim._place(job)
+        res = self.sim.try_place(job)
         if isinstance(res, PlacementFailure):
             return {"placed": False, "reason": res.reason}
         return {"placed": True, "kind": res.kind, "gpus": list(res.gpus)}

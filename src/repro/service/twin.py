@@ -5,8 +5,9 @@ would it see — and what would it do to everyone else?"  The twin answers
 by forking the live v2 engine (a copy-on-fork deep snapshot: the running
 set, completion heap, link-load vectors, and queue all come along, so the
 fork's future is exactly the live cluster's future) and stepping the fork
-over a bounded horizon with :func:`~repro.service.state.drain_completions`
-— the same loop the live state itself uses.
+over a bounded horizon with
+:meth:`~repro.core.simulator.ClusterSimulator.finish_until` — the same
+step the live state itself uses.
 
 Per candidate strategy the fork swaps placement machinery (strategy
 object, routing, failure-memo policy) before placing the probe.  Jobs
@@ -34,7 +35,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 from ..core.jobs import Job
 from ..core.strategies import get_strategy
-from .state import PROBE_ID_BASE, LiveCluster, drain_completions
+from .state import PROBE_ID_BASE, LiveCluster
 
 __all__ = ["DigitalTwin"]
 
@@ -78,7 +79,7 @@ class DigitalTwin:
         if hit is not None:
             return hit
         fork = self.fork()
-        done = drain_completions(fork, fork.now + horizon)
+        done = fork.finish_until(fork.now + horizon)
         base = dict(done)
         # one fabric version in the cache at a time: stale entries can
         # never be read again (version only grows), so drop them
@@ -183,7 +184,7 @@ class DigitalTwin:
             out["gpus"] = list(p.gpus)
         elif probe.job_id in fork.frag_reason:
             out["blocked_on"] = fork.frag_reason[probe.job_id]
-        done = dict(drain_completions(fork, t0 + horizon))
+        done = dict(fork.finish_until(t0 + horizon))
         probe_fin = done.get(probe.job_id)
         out["finished_within_horizon"] = probe_fin is not None
         out["predicted_wait"] = (probe.start_time - t0

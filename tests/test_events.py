@@ -6,8 +6,8 @@ The event subsystem (repro.core.events) must satisfy three contracts:
      failures fence resources and kill exactly the jobs touching them,
      resize restarts at the new size, defrag only ever moves a job to a
      strictly more local placement.
-  2. **Parity** — v1 ≡ v2 and incremental ≡ full stay bit-identical under
-     any event trace (the events extension of the engine contract).
+  2. **Parity** — v2 ≡ the v1 full-recompute reference stays bit-identical
+     under any event trace (the events extension of the engine contract).
   3. **Replay** — a fixed ``SimConfig.seed`` yields a bit-identical event
      log and metrics regardless of campaign workers / store mode.
 """
@@ -281,9 +281,11 @@ def test_defrag_never_degrades_locality_for_best():
 # engine parity under churn (the tentpole acceptance)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("strategy", ["ecmp", "sr", "best", "vclos"])
-def test_v2_matches_v1_with_events(strategy):
-    jobs, events = churn_fixture()
+@pytest.mark.parametrize("strategy,seed", [
+    ("ecmp", 3), ("sr", 3), ("best", 3), ("vclos", 3),
+    ("ecmp", 8), ("sr", 14)])
+def test_v2_matches_v1_with_events(strategy, seed):
+    jobs, events = churn_fixture(seed=seed)
     cfg = SimConfig(strategy=strategy, events=events, defrag_interval=4000.0)
     v1 = simulate(CLUSTER512, jobs, config=cfg, engine="v1")
     v2 = simulate(CLUSTER512, jobs, config=cfg, engine="v2")
@@ -309,21 +311,6 @@ def test_v2_matches_v1_with_events_extended(strategy):
     assert v1.jcts == v2.jcts
     assert v1.jwts == v2.jwts
     assert v1.event_log == v2.event_log
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("engine", ["v1", "v2"])
-def test_incremental_matches_full_with_events(engine):
-    jobs, events = churn_fixture()
-    cfg = SimConfig(strategy="ecmp", events=events, defrag_interval=4000.0)
-    inc = simulate(CLUSTER512, jobs, config=cfg, engine=engine,
-                   incremental=True)
-    full = simulate(CLUSTER512, jobs, config=cfg, engine=engine,
-                    incremental=False)
-    assert inc.jcts == full.jcts
-    assert inc.jwts == full.jwts
-    assert inc.slowdowns == full.slowdowns
-    assert inc.event_log == full.event_log
 
 
 def test_churn_golden_trace_jct_snapshot():

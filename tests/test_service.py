@@ -20,11 +20,13 @@ The contracts under test (ISSUE 8, docs/service.md):
 
 import copy
 import json
+import math
 
 import pytest
 
-from repro.core import (CLUSTER512, ClusterEvent, JournalMismatch, SimConfig,
-                        WorkloadSpec, generate_events, generate_trace)
+from repro.core import (CLUSTER512, ClusterEvent, ClusterSimulator,
+                        JournalMismatch, SimConfig, WorkloadSpec,
+                        generate_events, generate_trace)
 from repro.service import (DigitalTwin, LiveCluster, RecordingSimulator,
                            SchedClient, SchedulerService, ServerThread,
                            ServiceError, job_from_json, job_to_json,
@@ -84,6 +86,32 @@ def test_oracle_with_churn_events(strategy):
     assert rep_live.to_journal() == rep_off.to_journal()
     assert pl_live == pl_off
     assert rep_off.preemptions + rep_off.failures + rep_off.resizes > 0
+
+
+def test_step_surface_matches_simulate():
+    """The v2 step the daemon uses, driven by hand — ``arrive`` per job,
+    completions through ``next_finish`` / ``finish_next`` — gives the
+    offline run's completion order and JCTs."""
+    cfg = SimConfig(strategy="ecmp", **CFG)
+    jobs = trace(40, seed=9)
+    off_jobs = fresh(jobs)
+    rep_off = ClusterSimulator(CLUSTER512, config=cfg).run(off_jobs)
+    sim = ClusterSimulator(CLUSTER512, config=cfg)
+    mine = sorted(fresh(jobs), key=lambda j: (j.arrival, j.job_id))
+    done = []
+    for job in mine:
+        while sim.next_finish() <= job.arrival:
+            done.append(sim.finish_next())
+        sim.now = job.arrival
+        sim.arrive(job)
+    while sim.next_finish() < math.inf:
+        done.append(sim.finish_next())
+    off_done = sorted((j.finish_time, j.job_id) for j in off_jobs)
+    assert [(t, jid) for jid, t in done] == off_done
+    assert len(done) == len(jobs)
+    rep = sim.build_report(mine)
+    assert rep.jcts == rep_off.jcts
+    assert rep.jwts == rep_off.jwts
 
 
 def test_report_counts_denied_free(tmp_path):

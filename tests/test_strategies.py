@@ -169,9 +169,9 @@ def test_simconfig_matches_legacy_kwargs():
                                        deadline_slack=(1.5, 4.0)))
     for engine in ("v1", "v2"):
         legacy = simulate(CLUSTER512, jobs, "ecmp", scheduler="edf", seed=4,
-                          incremental=True, engine=engine)
+                          engine=engine)
         cfg = SimConfig(strategy="ecmp", scheduler="edf", seed=4,
-                        incremental=True, engine=engine)
+                        engine=engine)
         unified = simulate(CLUSTER512, jobs, config=cfg)
         assert legacy.jcts == unified.jcts
         assert legacy.jwts == unified.jwts
